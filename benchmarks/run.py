@@ -974,6 +974,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--filter", default="")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     print("name,us_per_call,derived")
     for name, fn in BENCHES.items():
         if args.filter and args.filter not in name:

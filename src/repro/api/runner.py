@@ -209,13 +209,13 @@ def _specs_compatible(a: ExperimentSpec, b: ExperimentSpec) -> bool:
 
 
 def _make_mesh(spec: ExperimentSpec):
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_host_mesh, make_mesh
 
     shape = spec.execution.mesh_shape
     if shape is None:
         return make_host_mesh()
     axes = ("pod", "data", "model") if len(shape) == 3 else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def _zoo_segment_and_state(built: BuiltExperiment):
@@ -287,6 +287,7 @@ def _run_zoo(built: BuiltExperiment, ckpt_manager, publish=None) -> History:
             int(x) for x in np.asarray(state.metrics["deadline_dropped"])
         ]
     hist.final_params = jax.tree_util.tree_map(np.asarray, params)
+    hist.segment = segment
     hist.wall_time_s = time.time() - t0
     return hist
 

@@ -476,13 +476,6 @@ class CompressionSpec:
                 f"unknown delta_dtype {self.delta_dtype!r}; "
                 f"options: {[d for d in _DELTA_DTYPES if d]} or null"
             )
-        if self.delta_dtype == "fp8":
-            import jax.numpy as jnp
-
-            if not hasattr(jnp, "float8_e4m3fn"):
-                raise ValueError(
-                    "delta_dtype 'fp8' needs jnp.float8_e4m3fn (jax too old)"
-                )
         if int(self.scale_block) <= 0:
             raise ValueError(
                 f"scale_block must be positive, got {self.scale_block}"

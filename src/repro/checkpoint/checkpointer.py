@@ -4,8 +4,13 @@ Saves model params, server-optimizer state, and sampler state (the K-Vib
 cumulative feedback omega is part of the training state — a restarted server
 must not forget what it learned about clients).
 
-Layout:  <dir>/<name>.npz          flat arrays keyed by index
+Layout:  <dir>/<name>.npz          flat arrays keyed by index, plus
+                                   ``dtypes``: every leaf's dtype name
          <dir>/<name>.treedef.txt  str(jax.tree_util.tree_structure)
+
+npz keeps only the width of the dtypes numpy does not define itself
+(bfloat16, the float8 family — ``ml_dtypes``), so those leaves are stored as
+same-width unsigned bit patterns and viewed back by the recorded name.
 Both files are published atomically (tmp + ``os.replace``) so a crash mid-save
 can never leave a half-written file under the final name.  Restore requires a
 template pytree with matching structure (the standard "abstract state"
@@ -22,6 +27,7 @@ from __future__ import annotations
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["save_checkpoint", "restore_checkpoint"]
@@ -31,11 +37,25 @@ def _sidecar_path(fname: str) -> str:
     return fname[: -len(".npz")] + ".treedef.txt"
 
 
+def _npz_keeps(dtype) -> bool:
+    """Whether an npz round trip returns ``dtype`` itself."""
+    fmt = np.lib.format
+    try:
+        return fmt.descr_to_dtype(fmt.dtype_to_descr(dtype)) == dtype
+    except (TypeError, ValueError):
+        return False
+
+
 def save_checkpoint(path: str, state) -> str:
     """Write `state` (any pytree of arrays) to `<path>.npz`. Returns the file."""
     leaves, treedef = jax.tree_util.tree_flatten(state)
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    arrays = {f"leaf_{i}": np.asarray(x) for i, x in enumerate(leaves)}
+    leaves = [np.asarray(x) for x in leaves]
+    arrays = {
+        f"leaf_{i}": x if _npz_keeps(x.dtype) else x.view(f"u{x.dtype.itemsize}")
+        for i, x in enumerate(leaves)
+    }
+    arrays["dtypes"] = np.array([x.dtype.name for x in leaves], dtype=str)
     fname = path if path.endswith(".npz") else path + ".npz"
     sidecar = _sidecar_path(fname)
     # Stage BOTH files before publishing EITHER: a crash can leave stale tmp
@@ -68,7 +88,8 @@ def restore_checkpoint(path: str, template):
             f"  saved:    {saved_treedef}\n  template: {treedef}"
         )
     with np.load(fname) as data:
-        n = len(data.files)
+        names = data["dtypes"] if "dtypes" in data.files else None
+        n = len(data.files) - (names is not None)
         if n != len(leaves_t):
             raise ValueError(
                 f"checkpoint has {n} leaves, template has {len(leaves_t)}"
@@ -76,6 +97,8 @@ def restore_checkpoint(path: str, template):
         leaves = []
         for i, t in enumerate(leaves_t):
             arr = data[f"leaf_{i}"]
+            if names is not None and arr.dtype.name != str(names[i]):
+                arr = arr.view(jnp.dtype(str(names[i])))
             t_arr = np.asarray(t)
             if arr.shape != t_arr.shape:
                 raise ValueError(

@@ -127,20 +127,37 @@ def aggregate_and_error(updates, weights: jax.Array, lam: jax.Array):
     w2 = jnp.stack(
         [weights.astype(jnp.float32), weights.astype(jnp.float32) - lam.astype(jnp.float32)]
     )
-    d_dim = flat.shape[1]
-    if jax.default_backend() == "tpu" and d_dim % 128 == 0:
+    if _on_tpu():
         from repro.kernels.fused_weighted_agg import fused_multi_weighted_agg
 
-        out = fused_multi_weighted_agg(flat, w2, block_d=_block_d(d_dim))
+        bd = _block_d(flat.shape[1])
+        out = fused_multi_weighted_agg(_pad_cols(flat, bd), w2, block_d=bd)
     else:
         out = w2 @ flat
     return _unflatten_vector(out[0], spec), jnp.sum(out[1] ** 2)
 
 
+def _on_tpu() -> bool:
+    """The aggregation kernels run on every TPU call; the jnp contractions
+    are the CPU path and the reference the kernel tests compare against."""
+    return jax.default_backend() == "tpu"
+
+
+_LANE = 128
+_MAX_BLOCK_D = 2048
+
+
 def _block_d(d_dim: int) -> int:
-    return d_dim if d_dim <= 2048 else max(
-        b for b in (2048, 1024, 512, 256, 128) if d_dim % b == 0
-    )
+    """Kernel chunk width for a D-wide row: the whole row rounded up to a
+    lane multiple when it is short, else ``_MAX_BLOCK_D``."""
+    return min(-(-d_dim // _LANE) * _LANE, _MAX_BLOCK_D)
+
+
+def _pad_cols(flat: jax.Array, multiple: int) -> jax.Array:
+    """Zero-pad the trailing axis of (C, D) to a multiple of ``multiple``;
+    zero columns add nothing to any weighted sum or squared norm."""
+    pad = (-flat.shape[1]) % multiple
+    return jnp.pad(flat, ((0, 0), (0, pad))) if pad else flat
 
 
 def aggregate_and_error_cohort(updates, weights: jax.Array, lam_cohort: jax.Array):
@@ -161,14 +178,15 @@ def aggregate_and_error_cohort(updates, weights: jax.Array, lam_cohort: jax.Arra
     Returns (estimate pytree, scalar squared error).
     """
     flat, spec = _flatten_stacked(updates)
-    d_dim = flat.shape[1]
-    if jax.default_backend() == "tpu" and d_dim % 128 == 0:
+    if _on_tpu():
         from repro.kernels.fused_weighted_agg import fused_cohort_agg_and_error
 
+        d_dim = flat.shape[1]
+        bd = _block_d(d_dim)
         d_vec, sq = fused_cohort_agg_and_error(
-            flat, weights, lam_cohort, block_d=_block_d(d_dim)
+            _pad_cols(flat, bd), weights, lam_cohort, block_d=bd
         )
-        return _unflatten_vector(d_vec, spec), sq
+        return _unflatten_vector(d_vec[:d_dim], spec), sq
     w2 = jnp.stack(
         [
             weights.astype(jnp.float32),
@@ -201,6 +219,7 @@ def aggregate_compressed(
     estimator actually saw.
     """
     from repro.kernels.fused_weighted_agg import (
+        dequant_block_d,
         dequant_cohort_agg_reference,
         fused_dequant_cohort_agg,
         quantize_stacked,
@@ -208,25 +227,25 @@ def aggregate_compressed(
 
     flat, spec = _flatten_stacked(updates)
     d_dim = flat.shape[1]
+    sb = int(compression.scale_block)
+    on_tpu = _on_tpu()
+    # On TPU, pad D to whole kernel chunks up front: zero blocks quantize to
+    # zero codes under scale 1.0 and contribute nothing.
     q, scales = quantize_stacked(
-        flat, dtype=compression.delta_dtype, scale_block=int(compression.scale_block)
+        _pad_cols(flat, dequant_block_d(d_dim, sb)) if on_tpu else flat,
+        dtype=compression.delta_dtype,
+        scale_block=sb,
     )
-    d_pad = q.shape[1]
-    sb = d_pad // scales.shape[1]
-    if (
-        jax.default_backend() == "tpu"
-        and d_pad % 128 == 0
-        and _block_d(d_pad) % sb == 0
-    ):
-        d_vec, sq, sqn = fused_dequant_cohort_agg(
-            q, scales, weights, lam_cohort, block_d=_block_d(d_pad)
-        )
+    if on_tpu:
+        d_vec, sq, sqn = fused_dequant_cohort_agg(q, scales, weights, lam_cohort)
     else:
         d_vec, sq, sqn = dequant_cohort_agg_reference(q, scales, weights, lam_cohort)
     d_hat = d_vec[:d_dim]
     new_resid = None
     if resid is not None:
-        d_true = weights.astype(jnp.float32) @ flat
+        d_true = jnp.matmul(
+            weights.astype(jnp.float32), flat, precision=jax.lax.Precision.HIGHEST
+        )
         new_resid = d_true - d_hat
         d_hat = d_hat + resid
     return _unflatten_vector(d_hat, spec), sq, jnp.sqrt(sqn), new_resid

@@ -267,7 +267,6 @@ def _isp_solve_sharded(
 ) -> jax.Array:
     """Solve over a (N,) score vector split across ``shard.axis`` of the
     ``shard`` (a launch.mesh.ShardSpec) mesh.  See _isp_solve_local."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     n = a.shape[0]
@@ -276,7 +275,7 @@ def _isp_solve_sharded(
         jnp.concatenate([a, jnp.full((pad,), jnp.inf, a.dtype)]) if pad else a
     )
     spec = PartitionSpec(shard.axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _isp_solve_local,
             n_global=n,
@@ -287,7 +286,7 @@ def _isp_solve_sharded(
         mesh=shard.mesh(),
         in_specs=(spec, PartitionSpec(), PartitionSpec()),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     p = fn(a_pad, budget, p_min)
     return p[:n] if pad else p

@@ -16,9 +16,13 @@ import numpy as np
 __all__ = ["FederatedDataset", "synthetic_classification", "synthetic_tokens"]
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class FederatedDataset:
-    """Padded per-client data: features (N, S_max, ...), labels (N, S_max)."""
+    """Padded per-client data: features (N, S_max, ...), labels (N, S_max).
+
+    A pytree, so round programs take it as a jit argument rather than
+    compiling its arrays in as constants."""
 
     features: jax.Array
     labels: jax.Array

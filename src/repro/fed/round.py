@@ -573,7 +573,10 @@ def build_fed_scan_segment(
         )
 
     placement = None
-    if getattr(sampler, "shard", None) is not None:
+    if mesh is not None or getattr(sampler, "shard", None) is not None:
+        # The body's sharding constraints commit its outputs to the mesh; a
+        # canonical placement gives fresh, carried and restored states the
+        # same layout, so the segment compiles once.
         # Shape-only template: the metrics dict's structure (and its lack of
         # any (N,)-axis buffer) is the same for every horizon length, so a
         # 1-round buffer set is enough to derive the placement pytree.
@@ -601,7 +604,9 @@ def build_fed_scan_segment(
             faults=f_state_s,
             compression=c_state_s,
         )
-        placement = build_placement(template, sampler)
+        placement = build_placement(
+            template, sampler, mesh if mesh is not None else sampler.shard.mesh()
+        )
 
     segment = make_segment_fn(
         body, derive_step,

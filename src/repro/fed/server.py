@@ -170,6 +170,9 @@ class History:
     regret: RegretTracker | None = None
     wall_time_s: float = 0.0
     final_params: object = None  # trained parameter pytree (trajectory probe)
+    # The compiled path's segment function, for probes of the program that
+    # ran: ``segment._cache_size()``, ``segment.lower(state, n).compile()``.
+    segment: object = None
 
     def summary(self) -> dict:
         out = {
@@ -210,10 +213,9 @@ def _split_batch_keys(key, n: int, local_steps: int):
     return jax.random.split(key, n * local_steps).reshape(n, local_steps, 2)
 
 
-def _build_all_clients(task: Task, dataset, cfg: FedConfig):
+def _build_all_clients(task: Task, dataset, cfg: FedConfig, lam):
     """All-clients local-update step (oracle mode): vmapped over clients."""
 
-    lam = dataset.lam
     n = dataset.n_clients
     one_client = _build_client_step(task, dataset, cfg)
 
@@ -248,10 +250,18 @@ def _build_cohort_clients(task: Task, dataset, cfg: FedConfig):
     return cohort_clients
 
 
-def _build_round_body(task: Task, dataset, sampler: samplers.Sampler, cfg: FedConfig, eval_data):
+def _build_round_body(
+    task: Task, dataset, sampler: samplers.Sampler, cfg: FedConfig, eval_data,
+    lam=None,
+):
     """One federated round as a scan body: (carry, (t, k_data, k_sample)) ->
     (carry, per-round metrics dict).  Pure and shape-static, so it runs
     identically under ``lax.scan`` and under per-round ``jit`` dispatch.
+
+    ``lam`` (default ``dataset.lam``) is the client weight vector.  The
+    compiled paths pass the dataset and ``lam`` in as jit arguments
+    (``_round_data``), ``lam`` computed once outside: computed inside each
+    program, its sum would round differently from one program to another.
 
     Oracle mode trains all N clients; deployable mode (oracle_metrics=False)
     trains only the C-slot cohort selected from the draw and aggregates at
@@ -280,10 +290,11 @@ def _build_round_body(task: Task, dataset, sampler: samplers.Sampler, cfg: FedCo
     ``compression=None`` the built body is the exact pre-compression
     program."""
 
-    lam = dataset.lam
+    if lam is None:
+        lam = dataset.lam
     n = dataset.n_clients
     if cfg.oracle_metrics:
-        all_clients = _build_all_clients(task, dataset, cfg)
+        all_clients = _build_all_clients(task, dataset, cfg, lam)
     else:
         c_slots = cfg.cohort_slots(n)
         cohort_clients = _build_cohort_clients(task, dataset, cfg)
@@ -515,6 +526,12 @@ def _build_round_body(task: Task, dataset, sampler: samplers.Sampler, cfg: FedCo
     return body
 
 
+def _round_data(dataset):
+    """``(dataset, lam)``: what the compiled round programs take as jit
+    arguments, so no client data is a constant of the program."""
+    return dataset, dataset.lam
+
+
 def round_body_for_lint(
     task: Task,
     dataset,
@@ -668,6 +685,10 @@ def build_segment_runner(
     ``donate=False`` keeps the input state alive across calls (benchmarks
     re-time the same state; donation would invalidate it on non-CPU
     backends)."""
+    def build_body(data):
+        ds, lam = data
+        return _build_round_body(task, ds, sampler, cfg, eval_data, lam)
+
     body = _build_round_body(task, dataset, sampler, cfg, eval_data)
     fault_on = cfg.faults is not None
     ef_on = cfg.compression is not None and bool(cfg.compression.error_feedback)
@@ -722,12 +743,15 @@ def build_segment_runner(
         compression=c_state,
     )
     placement = (
-        build_placement(init_state, sampler) if sampler.shard is not None else None
+        build_placement(init_state, sampler, sampler.shard.mesh())
+        if sampler.shard is not None
+        else None
     )
     segment = make_segment_fn(
-        body, _derive_keys_step,
+        build_body, _derive_keys_step,
         with_opt_state=True, with_round_index=True, with_faults=fault_on,
         with_compression=ef_on, donate=donate, placement=placement,
+        data=_round_data(dataset),
     )
     return segment, init_state
 
@@ -833,9 +857,15 @@ def run_federated(
         round_keys = derive_keys(key, cfg.rounds)  # (T, 2, key_dim)
         ts = jnp.arange(cfg.rounds, dtype=jnp.int32)
 
-        body = _build_round_body(task, dataset, sampler, cfg, eval_data)
+        # The dataset is an argument, as in the segmented path: both paths
+        # compile the same program form, and no data is a program constant.
+        def step_fn(carry, xs, data):
+            ds, lam = data
+            return _build_round_body(task, ds, sampler, cfg, eval_data, lam)(carry, xs)
+
+        round_data = _round_data(dataset)
         donate = jax.default_backend() != "cpu"
-        step = jax.jit(body, donate_argnums=(0,) if donate else ())
+        step = jax.jit(step_fn, donate_argnums=(0,) if donate else ())
         per_round = []
         for t in range(cfg.rounds):
             carry_in = (params, opt_state, s_state)
@@ -846,6 +876,7 @@ def run_federated(
             carry, m = step(
                 carry_in,
                 (ts[t], round_keys[t, 0], round_keys[t, 1]),
+                round_data,
             )
             if ef_on:
                 carry, c_state = carry[:-1], carry[-1]
@@ -875,6 +906,8 @@ def run_federated(
                 metrics["accuracy"] = np.zeros(0)
 
     hist = _materialize_history(metrics, cfg, has_eval=eval_data is not None)
+    if cfg.compiled:
+        hist.segment = segment
     hist.final_params = jax.tree_util.tree_map(np.asarray, params)
     hist.wall_time_s = time.time() - t0
     return hist

@@ -118,9 +118,10 @@ def init_metric_buffers(body, carry, xs_example, total_rounds: int):
     )
 
 
-def build_placement(template: TrainState, sampler) -> TrainState:
-    """Canonical ``TrainState`` device-placement pytree for a mesh-sharded
-    sampler, handed to ``make_segment_fn(placement=...)``.
+def build_placement(template: TrainState, sampler, mesh) -> TrainState:
+    """Canonical ``TrainState`` device-placement pytree over ``mesh`` — the
+    one mesh the round body is constrained to and the sampler shard (if any)
+    lives on — handed to ``make_segment_fn(placement=...)``.
 
     ``template`` only needs shapes/dtypes — concrete arrays and
     ``ShapeDtypeStruct`` pytrees both work.  Rule: sampler-state leaves with
@@ -128,6 +129,7 @@ def build_placement(template: TrainState, sampler) -> TrainState:
     metric buffers with a trailing (N,) axis (the oracle score history)
     split that axis the same way; every other leaf — params, optimizer
     state, scalar metrics, round counter, key — is explicitly replicated.
+    Without a sampler shard every leaf is replicated over ``mesh``.
     Making the whole carry's placement explicit (not just the sharded
     leaves) is what keeps the jit cache at one entry: fresh states, carried
     outputs, and numpy-round-tripped restores all ``device_put`` onto this
@@ -138,11 +140,15 @@ def build_placement(template: TrainState, sampler) -> TrainState:
     express an uneven split, while the in-trace sharding constraints can
     (GSPMD pads internally), so compute stays sharded either way."""
     shard = sampler.shard
-    mesh = shard.mesh()
+    if shard is not None and shard.mesh() != mesh:
+        raise ValueError(
+            f"sampler shard mesh {shard.axes} differs from the placement mesh "
+            f"{dict(mesh.shape)}"
+        )
     rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
-    row = shard.named_sharding(mesh)
     n = sampler.n
-    divisible = n % shard.num_shards == 0
+    divisible = shard is not None and n % shard.num_shards == 0
+    row = shard.named_sharding(mesh) if shard is not None else rep
 
     def sampler_rule(leaf):
         if divisible and leaf.ndim >= 1 and leaf.shape[0] == n:
@@ -184,6 +190,7 @@ def make_segment_fn(
     with_compression: bool = False,
     donate: bool = True,
     placement=None,
+    data=None,
 ):
     """The ONE implementation of a jitted scan segment over ``TrainState``.
 
@@ -231,11 +238,21 @@ def make_segment_fn(
     so this stays bitwise-neutral), a ring write for shorter host-offload
     buffers (``fed.server`` score-history offload allocates
     ``(ckpt_every, N)`` and drains to host every segment).
+
+    ``data`` (a pytree of arrays, e.g. the ``FederatedDataset``) enters the
+    jit as an argument, and ``body`` is then ``body(data) -> scan body``:
+    the arrays stay device buffers the program reads instead of constants
+    compiled into it (a 10^6-client dataset is gigabytes of constant).
+
+    ``segment.lower(state, n_rounds)`` lowers the program the calls run
+    (its ``.compile()`` reuses their executable): ``memory_analysis()``
+    and the HLO of what actually ran.
     """
     donate_argnums = (0,) if donate and jax.default_backend() != "cpu" else ()
 
-    @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=donate_argnums)
-    def segment(state: TrainState, n_rounds: int) -> TrainState:
+    @functools.partial(jax.jit, static_argnums=(2,), donate_argnums=donate_argnums)
+    def scan_segment(state: TrainState, data, n_rounds: int) -> TrainState:
+        scan_body = body if data is None else body(data)
         key, pairs = jax.lax.scan(derive_step, state.key, None, length=n_rounds)
         if with_opt_state:
             carry = (state.params, state.opt_state, state.sampler)
@@ -250,7 +267,7 @@ def make_segment_fn(
             xs = (ts, pairs[:, 0], pairs[:, 1])
         else:
             xs = pairs
-        carry, stacked = jax.lax.scan(body, carry, xs)
+        carry, stacked = jax.lax.scan(scan_body, carry, xs)
         if with_compression:
             carry, c_state = carry[:-1], carry[-1]
         else:
@@ -296,13 +313,16 @@ def make_segment_fn(
         "placement": placement,
     }
 
-    if placement is not None:
-        jitted = segment
+    def place(state: TrainState) -> TrainState:
+        return state if placement is None else jax.device_put(state, placement)
 
-        def segment(state: TrainState, n_rounds: int) -> TrainState:
-            return jitted(jax.device_put(state, placement), n_rounds)
+    def segment(state: TrainState, n_rounds: int) -> TrainState:
+        return scan_segment(place(state), data, n_rounds)
 
-        segment._cache_size = jitted._cache_size
+    segment._cache_size = scan_segment._cache_size
+    segment.lower = lambda state, n_rounds: scan_segment.lower(
+        place(state), data, n_rounds
+    )
 
     # Lintable handles for the static checkers (repro.analysis.lint):
     # audit_compile_once reads the declared donation setup from here and the

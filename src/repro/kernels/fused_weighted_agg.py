@@ -31,6 +31,7 @@ __all__ = [
     "quantize_stacked",
     "dequantize_stacked",
     "dequant_cohort_agg_reference",
+    "dequant_block_d",
     "fused_dequant_cohort_agg",
 ]
 
@@ -39,15 +40,17 @@ __all__ = [
 # float8_e4m3fn's largest finite value is 448.
 _QMAX = {"int8": 127.0, "fp8": 448.0}
 
+# The weight contractions run in full f32: the estimate is a weighted sum
+# whose terms can cancel, and a one-pass bf16 MXU product would round the
+# weights to 8 mantissa bits.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def quant_dtype(name: str):
-    """jnp dtype for a delta-width name ('int8' | 'fp8'); raises if the
-    installed jax lacks fp8 support."""
+    """jnp dtype for a delta-width name ('int8' | 'fp8')."""
     if name == "int8":
         return jnp.int8
     if name == "fp8":
-        if not hasattr(jnp, "float8_e4m3fn"):
-            raise ValueError("fp8 delta width needs jnp.float8_e4m3fn (jax too old)")
         return jnp.float8_e4m3fn
     raise ValueError(f"unknown delta dtype {name!r}")
 
@@ -108,7 +111,7 @@ def dequant_cohort_agg_reference(
     w2 = jnp.stack(
         [w.astype(jnp.float32), w.astype(jnp.float32) - lam_c.astype(jnp.float32)]
     )
-    out = jnp.einsum("mc,cbs->mbs", w2, blocks).reshape(2, d_pad)
+    out = jnp.einsum("mc,cbs->mbs", w2, blocks, precision=_HIGHEST).reshape(2, d_pad)
     sq_norms = jnp.sum(blocks * blocks, axis=(1, 2))
     return out[0], jnp.sum(out[1] ** 2), sq_norms
 
@@ -167,7 +170,9 @@ def fused_weighted_agg(
 def _multi_kernel(g_ref, w_ref, d_ref):
     g = g_ref[...].astype(jnp.float32)  # (C, BD)
     w = w_ref[...].astype(jnp.float32)  # (M, C)
-    d_ref[...] = jnp.dot(w, g, preferred_element_type=jnp.float32)
+    d_ref[...] = jnp.dot(
+        w, g, precision=_HIGHEST, preferred_element_type=jnp.float32
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -199,22 +204,22 @@ def fused_multi_weighted_agg(
     )(g, w)
 
 
-def _cohort_kernel(g_ref, w2_ref, d_ref, err_ref, acc_ref, *, n_chunks):
-    ic = pl.program_id(0)
-
-    @pl.when(ic == 0)
+def _cohort_kernel(g_ref, w2_ref, d_ref, err_ref):
+    # err_ref's block index never changes, so the (1, 128) output tile stays
+    # resident across the sequential chunk grid and doubles as the
+    # accumulator (every lane holds the same running sum; TPU VMEM takes
+    # vector stores only, never a scalar).
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        err_ref[...] = jnp.zeros_like(err_ref)
 
     g = g_ref[...].astype(jnp.float32)  # (C, BD)
     w2 = w2_ref[...].astype(jnp.float32)  # (2, C)
-    out = jnp.dot(w2, g, preferred_element_type=jnp.float32)  # (2, BD)
+    out = jnp.dot(
+        w2, g, precision=_HIGHEST, preferred_element_type=jnp.float32
+    )  # (2, BD)
     d_ref[...] = out[:1]
-    acc_ref[0, 0] += jnp.sum(out[1] ** 2)
-
-    @pl.when(ic == n_chunks - 1)
-    def _done():
-        err_ref[...] = acc_ref[:1, :1]
+    err_ref[...] += jnp.sum(out[1:2] ** 2, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -244,9 +249,8 @@ def fused_cohort_agg_and_error(
     assert d % bd == 0, (d, bd)
     n_chunks = d // bd
     w2 = jnp.stack([w.astype(jnp.float32), w.astype(jnp.float32) - lam_c.astype(jnp.float32)])
-    kernel = functools.partial(_cohort_kernel, n_chunks=n_chunks)
     d_out, err = pl.pallas_call(
-        kernel,
+        _cohort_kernel,
         grid=(n_chunks,),
         in_specs=[
             pl.BlockSpec((c, bd), lambda ic: (0, ic)),
@@ -254,42 +258,44 @@ def fused_cohort_agg_and_error(
         ],
         out_specs=[
             pl.BlockSpec((1, bd), lambda ic: (0, ic)),
-            pl.BlockSpec((1, 1), lambda ic: (0, 0)),
+            pl.BlockSpec((1, 128), lambda ic: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, d), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, 128), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, 128), jnp.float32)],
         interpret=interpret,
     )(g, w2)
     return d_out[0], err[0, 0]
 
 
-def _dequant_cohort_kernel(
-    q_ref, s_ref, w2_ref, d_ref, err_ref, sqn_ref, acc_err, acc_sqn, *, n_chunks, sb
-):
-    ic = pl.program_id(0)
-
-    @pl.when(ic == 0)
+def _dequant_cohort_kernel(q_ref, s_ref, w2_ref, d_ref, err_ref, sqn_ref, *, sb):
+    # err_ref / sqn_ref keep one block index for the whole grid: resident
+    # vector accumulators, as in ``_cohort_kernel``.
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        acc_err[...] = jnp.zeros_like(acc_err)
-        acc_sqn[...] = jnp.zeros_like(acc_sqn)
+        err_ref[...] = jnp.zeros_like(err_ref)
+        sqn_ref[...] = jnp.zeros_like(sqn_ref)
 
     q = q_ref[...].astype(jnp.float32)  # (C, BD) widened in VMEM only
     s = s_ref[...].astype(jnp.float32)  # (C, BD // sb)
     c, bd = q.shape
     g = (q.reshape(c, bd // sb, sb) * s[:, :, None]).reshape(c, bd)
     w2 = w2_ref[...].astype(jnp.float32)  # (2, C)
-    out = jnp.dot(w2, g, preferred_element_type=jnp.float32)  # (2, BD)
+    out = jnp.dot(
+        w2, g, precision=_HIGHEST, preferred_element_type=jnp.float32
+    )  # (2, BD)
     d_ref[...] = out[:1]
-    acc_err[0, 0] += jnp.sum(out[1] ** 2)
-    acc_sqn[:, 0] += jnp.sum(g * g, axis=1)
+    err_ref[...] += jnp.sum(out[1:2] ** 2, axis=1, keepdims=True)
+    sqn_ref[...] += jnp.sum(g * g, axis=1, keepdims=True)
 
-    @pl.when(ic == n_chunks - 1)
-    def _done():
-        err_ref[...] = acc_err[:1, :1]
-        sqn_ref[...] = acc_sqn[:, :1]
+
+def dequant_block_d(d_pad: int, scale_block: int) -> int:
+    """Chunk width of ``fused_dequant_cohort_agg``: the whole row when it is
+    short, else 128 scale blocks, so the (C, chunk / scale_block) scales tile
+    is a whole 128-lane vector row (the TPU tiling rule for a block that is
+    not the full array).  Callers pad D_pad to a multiple of it."""
+    return min(d_pad, 128 * scale_block)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -299,7 +305,7 @@ def fused_dequant_cohort_agg(
     w: jax.Array,
     lam_c: jax.Array,
     *,
-    block_d: int = 2048,
+    block_d: int | None = None,
     interpret: bool = False,
 ):
     """Compressed-width ``fused_cohort_agg_and_error``: the (C, D_pad) stacked
@@ -311,6 +317,9 @@ def fused_dequant_cohort_agg(
 
     q (C, D_pad) int8|fp8 from ``quantize_stacked``; scales (C, nb) f32 with
     ``nb = D_pad / scale_block``; w / lam_c as in ``fused_cohort_agg_and_error``.
+    The chunk width ``block_d`` defaults to ``dequant_block_d``, which
+    D_pad must be a multiple of; a narrower one (a multiple of
+    ``scale_block``) is legal in interpret mode only.
 
     Returns (d (D_pad,) f32, err_sq scalar f32, sq_norms (C,) f32).
     """
@@ -318,13 +327,13 @@ def fused_dequant_cohort_agg(
     nb = scales.shape[1]
     assert d_pad % nb == 0, (d_pad, nb)
     sb = d_pad // nb
-    bd = min(block_d, d_pad)
+    bd = dequant_block_d(d_pad, sb) if block_d is None else min(block_d, d_pad)
     assert d_pad % bd == 0 and bd % sb == 0, (d_pad, bd, sb)
     n_chunks = d_pad // bd
     w2 = jnp.stack(
         [w.astype(jnp.float32), w.astype(jnp.float32) - lam_c.astype(jnp.float32)]
     )
-    kernel = functools.partial(_dequant_cohort_kernel, n_chunks=n_chunks, sb=sb)
+    kernel = functools.partial(_dequant_cohort_kernel, sb=sb)
     d_out, err, sqn = pl.pallas_call(
         kernel,
         grid=(n_chunks,),
@@ -335,17 +344,13 @@ def fused_dequant_cohort_agg(
         ],
         out_specs=[
             pl.BlockSpec((1, bd), lambda ic: (0, ic)),
-            pl.BlockSpec((1, 1), lambda ic: (0, 0)),
-            pl.BlockSpec((c, 1), lambda ic: (0, 0)),
+            pl.BlockSpec((1, 128), lambda ic: (0, 0)),
+            pl.BlockSpec((c, 128), lambda ic: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((c, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, 128), jnp.float32),
-            pltpu.VMEM((c, 128), jnp.float32),
+            jax.ShapeDtypeStruct((1, 128), jnp.float32),
+            jax.ShapeDtypeStruct((c, 128), jnp.float32),
         ],
         interpret=interpret,
     )(q, scales, w2)

@@ -15,14 +15,14 @@ accumulating, for all L levels at once:
   mid_sum[k] = sum of a_i with floors[k] < a_i < levels[k]
 
 Same block structure as ``ssd_scan.py``: a sequential chunk grid dimension
-with the running (3, L) accumulator carried in VMEM scratch, initialized via
-``pl.when`` on the first chunk.  No chunk's scores ever round-trip to HBM
-between grid steps.
+with the running (3, L, Q) per-lane accumulator carried in VMEM scratch,
+initialized via ``pl.when`` on the first chunk and lane-reduced on the last.
+No chunk's scores ever round-trip to HBM between grid steps.
 
   grid = (n_chunks,)                 chunks sequential (accumulator carry)
-  scores block  (1, Q)    VMEM       one chunk of shard-local scores
-  levels block  (2, L)    VMEM       [levels; floors], resident every step
-  acc           (3, L) f32 scratch   carried across chunks
+  scores block  (8, Q)    VMEM       8 rows of shard-local scores
+  levels block  (L, 2)    VMEM       [levels | floors], resident every step
+  acc        (3, L, Q) f32 scratch   per-lane partials carried across chunks
 
 Padding contract: score entries equal to +inf are inert (they sit above any
 finite level, so no count or sum includes them) — callers pad both the
@@ -43,6 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["waterfill_level_stats"]
 
 _LANE = 128
+_SUBLANE = 8
 
 
 def _kernel(s_ref, lv_ref, out_ref, acc_ref):
@@ -52,20 +53,25 @@ def _kernel(s_ref, lv_ref, out_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = s_ref[0].astype(jnp.float32)  # (Q,)
-    levels = lv_ref[0].astype(jnp.float32)  # (L,)
-    floors = lv_ref[1].astype(jnp.float32)  # (L,)
+    # Levels run down the sublanes and scores along the lanes, so each
+    # comparison is a plain (L, 1) x (1, Q) broadcast; the (3, L, Q)
+    # accumulator keeps per-lane partials and is lane-reduced once, at the
+    # last chunk.
+    levels = lv_ref[:, 0:1].astype(jnp.float32)  # (L, 1)
+    floors = lv_ref[:, 1:2].astype(jnp.float32)  # (L, 1)
+    for r in range(s_ref.shape[0]):
+        a = s_ref[r : r + 1, :].astype(jnp.float32)  # (1, Q)
+        below = a < levels  # (L, Q)
+        at_floor = a <= floors
+        in_mid = jnp.logical_and(~at_floor, below)
+        acc_ref[0] += below.astype(jnp.float32)
+        acc_ref[1] += at_floor.astype(jnp.float32)
+        acc_ref[2] += jnp.where(in_mid, a, 0.0)
 
-    below = a[:, None] < levels[None, :]  # (Q, L)
-    at_floor = a[:, None] <= floors[None, :]
-    in_mid = jnp.logical_and(~at_floor, below)
-
-    acc_ref[0, :] = acc_ref[0, :] + jnp.sum(below.astype(jnp.float32), axis=0)
-    acc_ref[1, :] = acc_ref[1, :] + jnp.sum(at_floor.astype(jnp.float32), axis=0)
-    acc_ref[2, :] = acc_ref[2, :] + jnp.sum(
-        jnp.where(in_mid, a[:, None], 0.0), axis=0
-    )
-    out_ref[...] = acc_ref[...]
+    @pl.when(ic == pl.num_programs(0) - 1)
+    def _done():
+        for k in range(3):
+            out_ref[k] = jnp.sum(acc_ref[k], axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -85,27 +91,29 @@ def waterfill_level_stats(
     (m,) = scores.shape
     (l,) = levels.shape
     q = max(_LANE, min(chunk, -(-m // _LANE) * _LANE))
-    m_pad = -(-max(m, 1) // q) * q
-    l_pad = -(-l // _LANE) * _LANE
-    s2 = jnp.full((m_pad,), jnp.inf, jnp.float32).at[:m].set(
+    rows = -(-max(m, 1) // q)
+    rows = -(-rows // _SUBLANE) * _SUBLANE
+    s2 = jnp.full((rows * q,), jnp.inf, jnp.float32).at[:m].set(
         scores.astype(jnp.float32)
-    ).reshape(m_pad // q, q)
+    ).reshape(rows, q)
+    l_pad = -(-l // _SUBLANE) * _SUBLANE
     lv2 = jnp.stack(
         [
             jnp.ones((l_pad,), jnp.float32).at[:l].set(levels.astype(jnp.float32)),
             jnp.zeros((l_pad,), jnp.float32).at[:l].set(floors.astype(jnp.float32)),
-        ]
+        ],
+        axis=1,
     )
     out = pl.pallas_call(
         _kernel,
-        grid=(m_pad // q,),
+        grid=(rows // _SUBLANE,),
         in_specs=[
-            pl.BlockSpec((1, q), lambda ic: (ic, 0)),
-            pl.BlockSpec((2, l_pad), lambda ic: (0, 0)),
+            pl.BlockSpec((_SUBLANE, q), lambda ic: (ic, 0)),
+            pl.BlockSpec((l_pad, 2), lambda ic: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((3, l_pad), lambda ic: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((3, l_pad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((3, l_pad), jnp.float32)],
+        out_specs=pl.BlockSpec((3, l_pad, 1), lambda ic: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((3, l_pad, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((3, l_pad, q), jnp.float32)],
         interpret=interpret,
     )(s2, lv2)
-    return out[0, :l], out[1, :l], out[2, :l]
+    return out[0, :l, 0], out[1, :l, 0], out[2, :l, 0]

@@ -1,5 +1,8 @@
 import os
 
+# A CPU tool: it and the children it starts never take an accelerator (one
+# process owns a chip, and this one only compiles for described meshes).
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     os.environ.get("REPRO_EXTRA_XLA_FLAGS", "")
     + " --xla_force_host_platform_device_count="

@@ -15,11 +15,26 @@ import jax
 
 __all__ = [
     "ShardSpec",
+    "make_mesh",
     "make_production_mesh",
     "make_host_mesh",
     "fsdp_axes",
     "batch_axes",
 ]
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding-constraint
+    and ``shard_map`` code in this repo names mesh axes in
+    ``with_sharding_constraint``, which only ``Auto`` axes accept (the
+    installed JAX defaults to ``Explicit``).  ``devices`` defaults to all of
+    this process's devices."""
+    from jax.sharding import AxisType
+
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,7 +77,7 @@ class ShardSpec:
 
     def mesh(self):
         """Materialize the described mesh over this process's devices."""
-        return jax.make_mesh(
+        return make_mesh(
             tuple(s for _, s in self.axes), tuple(n for n, _ in self.axes)
         )
 
@@ -93,7 +108,7 @@ def _override_mesh():
         return None
     shape = tuple(int(x) for x in override.split(","))
     axes = ("pod", "data", "model") if len(shape) == 3 else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -102,22 +117,22 @@ def make_production_mesh(*, multi_pod: bool = False):
         return mesh
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """(data, model) mesh over whatever devices THIS host exposes.
 
     REPRO_MESH_SHAPE overrides (same contract as ``make_production_mesh``);
-    otherwise the model axis takes the largest of (16, 8, 4, 2, 1) dividing
-    the device count.  One CPU device yields the degenerate (1, 1) mesh, so
-    the mesh-parallel code path is exercised everywhere the tests run."""
+    otherwise every device goes on the ``data`` axis, which carries the
+    cohort (``client_parallel``) and the sampler's (N,) client axis — a
+    four-chip host gives ``(data=4, model=1)``.  One device yields the
+    degenerate (1, 1) mesh, so the mesh-parallel code path is exercised
+    everywhere the tests run."""
     mesh = _override_mesh()
     if mesh is not None:
         return mesh
-    n = len(jax.devices())
-    model = next(cand for cand in (16, 8, 4, 2, 1) if n % cand == 0)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((len(jax.devices()), 1), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer
 
 
@@ -229,6 +230,7 @@ def main() -> None:
     )
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.follow:
         _follow(args)

@@ -69,6 +69,7 @@ from repro.core.samplers import sampler_names
 from repro.fed import cohort as fed_cohort
 from repro.fed.round import build_fed_scan_segment, build_round_step
 from repro.fed.state import run_segmented
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer
 
@@ -363,6 +364,7 @@ def run_spec(spec: ExperimentSpec, *, ckpt: str = "", resume: bool = False) -> N
 def main(argv=None) -> None:
     ap = make_parser()
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.spec:
         spec = ExperimentSpec.load(args.spec)

@@ -131,7 +131,6 @@ def _moe_ffn_a2a(params: dict, cfg: ArchConfig, x: jax.Array, mesh):
     Collective volume: O(3 * T_local * k * d) per layer instead of the
     O(E * cap * d) full-buffer all-reduces of the GSPMD scatter path.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import batch_axes
@@ -201,7 +200,7 @@ def _moe_ffn_a2a(params: dict, cfg: ArchConfig, x: jax.Array, mesh):
         return out.reshape(xb.shape), aux
 
     bspec = b_axes if len(b_axes) > 1 else b_axes[0]
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -212,7 +211,7 @@ def _moe_ffn_a2a(params: dict, cfg: ArchConfig, x: jax.Array, mesh):
             P("model", None, None),
         ),
         out_specs=(P(bspec, "model", None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"], params["w_down"])
 
     if "dense" in params:

@@ -34,6 +34,26 @@ def test_restore_rejects_dtype_mismatch(tmp_path):
         restore_checkpoint(f, {"a": np.zeros((3,), np.int32)})
 
 
+@pytest.mark.parametrize(
+    "dtype", [jnp.bfloat16, jnp.float8_e4m3fn, jnp.float8_e5m2]
+)
+def test_ml_dtypes_round_trip_bitwise(tmp_path, dtype):
+    """bf16 params and fp8 delta rows survive npz, which keeps only the
+    width of these dtypes; a different dtype of the same width still raises."""
+    x = (jnp.arange(24, dtype=jnp.float32) / 7.0 - 1.5).astype(dtype).reshape(4, 6)
+    f = save_checkpoint(str(tmp_path / "c"), {"x": x, "y": jnp.ones((2,))})
+    got = restore_checkpoint(f, {"x": jnp.zeros_like(x), "y": jnp.zeros((2,))})
+    assert got["x"].dtype == x.dtype
+    np.testing.assert_array_equal(
+        np.asarray(got["x"]).view(np.uint8), np.asarray(x).view(np.uint8)
+    )
+    other = jnp.float16 if dtype == jnp.bfloat16 else (
+        jnp.float8_e5m2 if dtype == jnp.float8_e4m3fn else jnp.float8_e4m3fn
+    )
+    with pytest.raises(ValueError, match="dtype"):
+        restore_checkpoint(f, {"x": jnp.zeros(x.shape, other), "y": jnp.zeros((2,))})
+
+
 def test_restore_compares_saved_treedef(tmp_path):
     """The .treedef.txt sidecar is actually read back: a template with the
     same leaf count/shapes/dtypes but a different STRUCTURE must raise

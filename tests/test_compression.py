@@ -21,8 +21,7 @@ from repro.kernels.fused_weighted_agg import (
     quantize_stacked,
 )
 
-HAS_FP8 = hasattr(jnp, "float8_e4m3fn")
-DTYPES = ["int8"] + (["fp8"] if HAS_FP8 else [])
+DTYPES = ["int8", "fp8"]
 
 
 @pytest.fixture(scope="module")
@@ -231,18 +230,31 @@ def test_feedback_norms_tolerance_registry_sweep(tiny_ds, name):
 def test_error_feedback_recovers_f32_loss(tiny_ds):
     """The acceptance bound: int8 + error feedback lands allclose to the f32
     final loss (the residual telescopes, leaving one round's error), while
-    disabling EF accumulates a random walk that is measurably worse."""
-    h32 = _run(tiny_ds, "uniform_isp", rounds=25)
-    h_ef = _run(tiny_ds, "uniform_isp", rounds=25,
+    disabling EF accumulates a random walk that is measurably worse.
+
+    The walk is measured where it lives, in the parameters: near the optimum
+    the loss is flat to first order, so a final-loss gap understates the
+    drift and sits within noise of the EF run's one-round error (about 2x at
+    25 rounds).  Over 100 rounds the walk has had time to accumulate."""
+    rounds = 100
+    h32 = _run(tiny_ds, "uniform_isp", rounds=rounds)
+    h_ef = _run(tiny_ds, "uniform_isp", rounds=rounds,
                 compression=CompressionSpec(delta_dtype="int8"))
-    h_no = _run(tiny_ds, "uniform_isp", rounds=25,
+    h_no = _run(tiny_ds, "uniform_isp", rounds=rounds,
                 compression=CompressionSpec(delta_dtype="int8",
                                             error_feedback=False))
-    f32 = h32.train_loss[-1]
-    ef_err = abs(h_ef.train_loss[-1] - f32)
-    no_err = abs(h_no.train_loss[-1] - f32)
-    np.testing.assert_allclose(h_ef.train_loss[-1], f32, rtol=0, atol=2e-3)
-    assert no_err > 2 * ef_err, (
+    np.testing.assert_allclose(h_ef.train_loss[-1], h32.train_loss[-1],
+                               rtol=0, atol=2e-3)
+
+    def drift(h):
+        return float(np.sqrt(sum(
+            np.sum((np.asarray(a) - np.asarray(b)) ** 2)
+            for a, b in zip(jax.tree_util.tree_leaves(h.final_params),
+                            jax.tree_util.tree_leaves(h32.final_params))
+        )))
+
+    ef_err, no_err = drift(h_ef), drift(h_no)
+    assert no_err > 10 * ef_err, (
         f"EF off should drift measurably: |ef|={ef_err:.2e} |no-ef|={no_err:.2e}"
     )
 

@@ -144,7 +144,7 @@ def test_seeded_f64_leak_yields_exactly_one_dtype_finding():
         y = x.astype(jnp.float64)
         return (y * 2.0).sum()
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(leaky)(jax.ShapeDtypeStruct((N,), jnp.float32))
     findings = audit_dtypes(closed, target="leaky")
     assert len(findings) == 1, "\n".join(f.render() for f in findings)

@@ -113,6 +113,30 @@ def test_segment_runner_state_advances(tiny_ds):
     assert np.all(np.asarray(st.metrics["train_loss"]) != 0.0)
 
 
+def test_segment_takes_dataset_as_argument(tiny_ds):
+    """The round program reads the client data from its arguments: no
+    dataset array is a constant of the program, and ``run_federated``'s
+    ``History.segment`` lowers the program that ran without compiling it
+    again."""
+    cfg = FedConfig(rounds=4, budget=4, local_steps=1, batch_size=16, seed=3,
+                    oracle_metrics=False, cohort=4)
+    sampler = make_sampler("kvib", n=tiny_ds.n_clients, budget=4, horizon=4)
+    hist = run_federated(logistic_regression(), tiny_ds, sampler, cfg)
+    segment = hist.segment
+    assert segment._cache_size() == 1
+    _, state = build_segment_runner(logistic_regression(), tiny_ds, sampler, cfg)
+    lowered = segment.lower(state, cfg.rounds)
+    text = lowered.as_text()
+    feats = "x".join(str(d) for d in tiny_ds.features.shape)
+    assert f"tensor<{feats}xf32>" in text  # a parameter of the program
+    assert not any(
+        "stablehlo.constant" in line and feats in line
+        for line in text.splitlines()
+    )
+    assert lowered.compile().memory_analysis() is not None
+    assert segment._cache_size() == 1
+
+
 def test_preempt_checkpoint_resume_bitwise(tiny_ds, tmp_path):
     """Preemption simulation, in-process: run 2 of 5 segments with a manager,
     'restart' by restoring the latest committed step into a fresh template,

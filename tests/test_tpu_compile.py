@@ -1,0 +1,134 @@
+"""The main-path Pallas kernels compile for a TPU v5e at real widths.
+
+Interpret mode accepts kernels that the chip's compiler refuses (a scalar
+store to VMEM, a block not aligned to the (8, 128) tiling), so each kernel
+is compiled here for a *described* v5e — no chip attached — and its HLO
+must hold the Mosaic custom call.  The topology is described inside a
+fixture, never at import: only one process at a time may load the TPU
+compiler library.
+"""
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core import estimator
+from repro.models import transformer
+
+fwa = importlib.import_module("repro.kernels.fused_weighted_agg")
+swf = importlib.import_module("repro.kernels.sharded_waterfill")
+
+SMOKE_COHORT_INT8 = 2  # chip_smoke.Setup.cohort_int8
+SAMPLER_COHORT = 128  # 2 * K at K=64, the million-client logreg run
+LOGREG_D = 60 * 10 + 10
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler library here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # A described chip cannot read a persistent-cache entry back; keep the
+    # cache out of these compiles.
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def smollm_d():
+    """smollm-360m's flattened parameter count (shapes only)."""
+    cfg = get_config("smollm-360m")
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    return sum(s.size for s in jax.tree_util.tree_leaves(shapes))
+
+
+def _hlo(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _pad(d, block):
+    return -(-d // block) * block
+
+
+def test_fused_cohort_agg_and_error_compiles(one_chip, smollm_d):
+    spec = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    for c, d in ((SAMPLER_COHORT, _pad(LOGREG_D, 128)),
+                 (SMOKE_COHORT_INT8, _pad(smollm_d, 2048))):
+        hlo = _hlo(
+            lambda g, w, lam: fwa.fused_cohort_agg_and_error(
+                g, w, lam, block_d=min(d, 2048)),
+            spec((c, d)), spec((c,)), spec((c,)),
+        )
+        assert "tpu_custom_call" in hlo, (c, d)
+
+
+def test_fused_dequant_cohort_agg_compiles(one_chip, smollm_d):
+    c, sb = SMOKE_COHORT_INT8, 128
+    d_pad = _pad(smollm_d, fwa.dequant_block_d(smollm_d, sb))
+    args = (
+        jax.ShapeDtypeStruct((c, d_pad), jnp.int8, sharding=one_chip),
+        jax.ShapeDtypeStruct((c, d_pad // sb), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((c,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((c,), jnp.float32, sharding=one_chip),
+    )
+    assert "tpu_custom_call" in _hlo(fwa.fused_dequant_cohort_agg, *args)
+
+
+def test_fused_multi_weighted_agg_compiles(one_chip, smollm_d):
+    c, d = SMOKE_COHORT_INT8, _pad(smollm_d, 2048)
+    hlo = _hlo(
+        lambda g, w: fwa.fused_multi_weighted_agg(g, w, block_d=2048),
+        jax.ShapeDtypeStruct((c, d), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((2, c), jnp.float32, sharding=one_chip),
+    )
+    assert "tpu_custom_call" in hlo
+
+
+def test_waterfill_level_stats_compiles(one_chip):
+    n_shard = 250_000  # N=10^6 clients over four shards
+    hlo = _hlo(
+        swf.waterfill_level_stats,
+        jax.ShapeDtypeStruct((n_shard,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip),
+    )
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("path", ["cohort", "compressed"])
+def test_estimator_runs_kernel_at_any_width_on_tpu(one_chip, monkeypatch, path):
+    """On TPU the estimator pads D to whole kernel chunks instead of falling
+    back to jnp: logreg's D=610 is no multiple of 128.  The described chip
+    cannot steer ``jax.default_backend()``, so the test steers the
+    estimator's backend check."""
+    from repro.api import CompressionSpec
+
+    monkeypatch.setattr(estimator, "_on_tpu", lambda: True)
+    c = SAMPLER_COHORT
+    spec = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    updates = {"w": spec((c, 60, 10)), "b": spec((c, 10))}
+    if path == "cohort":
+        fn = estimator.aggregate_and_error_cohort
+    else:
+        comp = CompressionSpec(delta_dtype="int8")
+        fn = lambda u, w, lam: estimator.aggregate_compressed(u, w, lam, comp)
+    assert "tpu_custom_call" in _hlo(fn, updates, spec((c,)), spec((c,)))
