@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 __all__ = ["save_checkpoint", "restore_checkpoint"]
 
 
@@ -50,7 +52,8 @@ def save_checkpoint(path: str, state) -> str:
     """Write `state` (any pytree of arrays) to `<path>.npz`. Returns the file."""
     leaves, treedef = jax.tree_util.tree_flatten(state)
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    leaves = [np.asarray(x) for x in leaves]
+    with obs.span("ckpt.fetch", leaves=len(leaves)):
+        leaves = [np.asarray(x) for x in leaves]
     arrays = {
         f"leaf_{i}": x if _npz_keeps(x.dtype) else x.view(f"u{x.dtype.itemsize}")
         for i, x in enumerate(leaves)
@@ -60,14 +63,15 @@ def save_checkpoint(path: str, state) -> str:
     sidecar = _sidecar_path(fname)
     # Stage BOTH files before publishing EITHER: a crash can leave stale tmp
     # files but never a half-written .npz or .treedef.txt under its final name.
-    tmp = fname + ".tmp"
-    with open(tmp, "wb") as f:
-        np.savez(f, **arrays)
-    tmp_sidecar = sidecar + ".tmp"
-    with open(tmp_sidecar, "w") as f:
-        f.write(str(treedef))
-    os.replace(tmp, fname)  # atomic publish
-    os.replace(tmp_sidecar, sidecar)  # atomic publish
+    with obs.span("ckpt.write"):
+        tmp = fname + ".tmp"
+        with open(tmp, "wb") as f:
+            np.savez(f, **arrays)
+        tmp_sidecar = sidecar + ".tmp"
+        with open(tmp_sidecar, "w") as f:
+            f.write(str(treedef))
+        os.replace(tmp, fname)  # atomic publish
+        os.replace(tmp_sidecar, sidecar)  # atomic publish
     return fname
 
 

@@ -103,6 +103,10 @@ class CheckpointManager:
         # numpy, so a restoring process lays the arrays out per its OWN
         # ShardSpec — resuming onto a different mesh shape is legal.
         self.layout = layout
+        # What this manager has published: steps saved and the bytes of
+        # their checkpoint files (the .npz and its treedef sidecar).
+        self.saves = 0
+        self.bytes_written = 0
 
     # -- paths ---------------------------------------------------------------
     @property
@@ -136,6 +140,10 @@ class CheckpointManager:
         manifest commit (deleting a stale file can never un-commit a step)."""
         step = int(step)
         fname = save_checkpoint(self.checkpoint_path(step), state)
+        self.saves += 1
+        self.bytes_written += os.path.getsize(fname) + os.path.getsize(
+            fname[: -len(".npz")] + ".treedef.txt"
+        )
         prev = self.read_manifest()
         steps = sorted(set((prev.get("steps", []) if prev else [])) | {step})
         retained = steps[-self.keep_last :]

@@ -146,22 +146,24 @@ def build_round_step(cfg: ArchConfig, spec: RoundSpec, constrain=None) -> Callab
                 def one(tok, tgt, aux):
                     return per_client(params, tok, tgt, aux)
 
-                if aux_embeds is None:
-                    deltas, losses, _ = jax.vmap(
-                        lambda tok, tgt: one(tok, tgt, None)
-                    )(tokens, targets)
-                else:
-                    deltas, losses, _ = jax.vmap(one)(tokens, targets, aux_embeds)
+                with jax.named_scope("round.local_train"):
+                    if aux_embeds is None:
+                        deltas, losses, _ = jax.vmap(
+                            lambda tok, tgt: one(tok, tgt, None)
+                        )(tokens, targets)
+                    else:
+                        deltas, losses, _ = jax.vmap(one)(tokens, targets, aux_embeds)
                 # Compressed aggregation: the stacked cohort deltas are
                 # quantized and reduced by the fused dequant kernel; passing
                 # ``weights`` for lam_cohort zeroes the (unused here) error
                 # row.  Feedback norms come from the dequantized values.
-                d, _, norms, new_resid = estimator.aggregate_compressed(
-                    deltas, weights, weights, comp, resid
-                )
-                new_params = jax.tree_util.tree_map(
-                    lambda p, g: p - spec.server_lr * g.astype(p.dtype), params, d
-                )
+                with jax.named_scope("round.aggregate"):
+                    d, _, norms, new_resid = estimator.aggregate_compressed(
+                        deltas, weights, weights, comp, resid
+                    )
+                    new_params = jax.tree_util.tree_map(
+                        lambda p, g: p - spec.server_lr * g.astype(p.dtype), params, d
+                    )
                 return new_params, norms, cohort_mean_loss(losses, weights), new_resid
 
             return round_step
@@ -170,16 +172,18 @@ def build_round_step(cfg: ArchConfig, spec: RoundSpec, constrain=None) -> Callab
             def one(tok, tgt, aux):
                 return per_client(params, tok, tgt, aux)
 
-            if aux_embeds is None:
-                deltas, losses, norms = jax.vmap(
-                    lambda tok, tgt: one(tok, tgt, None)
-                )(tokens, targets)
-            else:
-                deltas, losses, norms = jax.vmap(one)(tokens, targets, aux_embeds)
-            d = weighted_delta_sum(deltas, weights)
-            new_params = jax.tree_util.tree_map(
-                lambda p, g: p - spec.server_lr * g.astype(p.dtype), params, d
-            )
+            with jax.named_scope("round.local_train"):
+                if aux_embeds is None:
+                    deltas, losses, norms = jax.vmap(
+                        lambda tok, tgt: one(tok, tgt, None)
+                    )(tokens, targets)
+                else:
+                    deltas, losses, norms = jax.vmap(one)(tokens, targets, aux_embeds)
+            with jax.named_scope("round.aggregate"):
+                d = weighted_delta_sum(deltas, weights)
+                new_params = jax.tree_util.tree_map(
+                    lambda p, g: p - spec.server_lr * g.astype(p.dtype), params, d
+                )
             return new_params, norms, cohort_mean_loss(losses, weights)
 
         return round_step
@@ -187,11 +191,12 @@ def build_round_step(cfg: ArchConfig, spec: RoundSpec, constrain=None) -> Callab
     if mode == "cohort_sequential":
 
         def round_step(params, tokens, targets, weights, aux_embeds=None):
-            acc0 = constrain(
-                jax.tree_util.tree_map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params
+            with jax.named_scope("round.aggregate"):
+                acc0 = constrain(
+                    jax.tree_util.tree_map(
+                        lambda p: jnp.zeros(p.shape, jnp.float32), params
+                    )
                 )
-            )
 
             def body(acc, inp):
                 if aux_embeds is None:
@@ -199,12 +204,17 @@ def build_round_step(cfg: ArchConfig, spec: RoundSpec, constrain=None) -> Callab
                     aux = None
                 else:
                     tok, tgt, w, aux = inp
-                delta, loss, norm = per_client(params, tok, tgt, aux)
-                delta = constrain(delta)
-                acc = jax.tree_util.tree_map(
-                    lambda a, dl: a + w * dl.astype(jnp.float32), acc, delta
-                )
-                return constrain(acc), (loss, norm)
+                # Members train and accumulate in turn; each half takes its
+                # own scope inside the unscoped scan.
+                with jax.named_scope("round.local_train"):
+                    delta, loss, norm = per_client(params, tok, tgt, aux)
+                with jax.named_scope("round.aggregate"):
+                    delta = constrain(delta)
+                    acc = jax.tree_util.tree_map(
+                        lambda a, dl: a + w * dl.astype(jnp.float32), acc, delta
+                    )
+                    acc = constrain(acc)
+                return acc, (loss, norm)
 
             xs = (
                 (tokens, targets, weights)
@@ -212,9 +222,10 @@ def build_round_step(cfg: ArchConfig, spec: RoundSpec, constrain=None) -> Callab
                 else (tokens, targets, weights, aux_embeds)
             )
             d, (losses, norms) = jax.lax.scan(body, acc0, xs)
-            new_params = jax.tree_util.tree_map(
-                lambda p, g: p - spec.server_lr * g.astype(p.dtype), params, d
-            )
+            with jax.named_scope("round.aggregate"):
+                new_params = jax.tree_util.tree_map(
+                    lambda p, g: p - spec.server_lr * g.astype(p.dtype), params, d
+                )
             return new_params, norms, cohort_mean_loss(losses, weights)
 
         return round_step
@@ -364,27 +375,33 @@ def _build_scan_body(cfg, spec, sampler, dataset, mesh, constrain):
             f_state = {}
             t = None
             k_draw, k_data = xs[0], xs[1]
-        p = sampler.probabilities(s_state)
-        draw = sampler.sample_from(p, k_draw)
+        with jax.named_scope("round.solve"):
+            p = sampler.probabilities(s_state)
+        with jax.named_scope("round.draw"):
+            draw = sampler.sample_from(p, k_draw)
         if avail_on:
             # Same fold_in streams (101/102/103) as the simulation stack, off
             # the draw key; the draw's own key material is untouched.
-            avail_mask, q_t, new_chain = stragglers.availability_step(
-                faults,
-                f_state.get("chain"),
-                t,
-                jax.random.fold_in(k_draw, 101),
-                n,
+            with jax.named_scope("round.faults"):
+                avail_mask, q_t, new_chain = stragglers.availability_step(
+                    faults,
+                    f_state.get("chain"),
+                    t,
+                    jax.random.fold_in(k_draw, 101),
+                    n,
+                )
+                avail_mask = sampler.shard_constrain(avail_mask)
+                q_t = sampler.shard_constrain(q_t)
+                draw = stragglers.available_draw(draw, avail_mask, q_t)
+                if "chain" in f_state:
+                    f_state = {**f_state, "chain": sampler.shard_constrain(new_chain)}
+        with jax.named_scope("round.select"):
+            w_full = estimator.client_weights(
+                draw, lam, sampler.procedure, sampler.budget
             )
-            avail_mask = sampler.shard_constrain(avail_mask)
-            q_t = sampler.shard_constrain(q_t)
-            draw = stragglers.available_draw(draw, avail_mask, q_t)
-            if "chain" in f_state:
-                f_state = {**f_state, "chain": sampler.shard_constrain(new_chain)}
-        w_full = estimator.client_weights(draw, lam, sampler.procedure, sampler.budget)
-        sel = select_cohort(
-            draw.mask, w_full, spec.cohort, jax.random.fold_in(k_draw, 1)
-        )
+            sel = select_cohort(
+                draw.mask, w_full, spec.cohort, jax.random.fold_in(k_draw, 1)
+            )
         overflow_dropped = sel.n_dropped
         deadline_dropped = jnp.zeros((), jnp.int32)
         if deadline_on:
@@ -392,13 +409,18 @@ def _build_scan_body(cfg, spec, sampler, dataset, mesh, constrain):
             # already scheduled it); late slots are demoted to inert padding
             # so only the aggregation weights / feedback / loss see the drop,
             # with survivors rescaled by 1/surv for unbiasedness.
-            lat_c = stragglers.latency_draw(
-                faults, (sel.valid.shape[0],), jax.random.fold_in(k_draw, 102)
-            )
-            late_c = jnp.logical_and(sel.valid, lat_c > jnp.float32(faults.deadline))
-            sel = mask_selection(sel, ~late_c, 1.0 / surv)
-            deadline_dropped = jnp.sum(late_c.astype(jnp.int32))
-        tokens, targets = gather_cohort(sel, k_data)
+            with jax.named_scope("round.faults"):
+                lat_c = stragglers.latency_draw(
+                    faults, (sel.valid.shape[0],), jax.random.fold_in(k_draw, 102)
+                )
+                late_c = jnp.logical_and(
+                    sel.valid, lat_c > jnp.float32(faults.deadline)
+                )
+                sel = mask_selection(sel, ~late_c, 1.0 / surv)
+                deadline_dropped = jnp.sum(late_c.astype(jnp.int32))
+        with jax.named_scope("round.gather"):
+            tokens, targets = gather_cohort(sel, k_data)
+        # round_step opens its own round.local_train and round.aggregate.
         if comp_on:
             new_params, norms, loss, new_resid = round_step(
                 params, tokens, targets, sel.weights, resid=c_state.get("resid")
@@ -411,29 +433,31 @@ def _build_scan_body(cfg, spec, sampler, dataset, mesh, constrain):
             # round_step already applied x - server_lr * d; recover the
             # update u = server_lr * d, route it through the carried (B, D)
             # stale-delta ring, and apply only what arrived this round.
-            u = jax.tree_util.tree_map(lambda a, b: a - b, params, new_params)
-            new_buf, apply_vec, _ = stragglers.async_step(
-                faults,
-                f_state["buf"],
-                stragglers.tree_to_vec(u),
-                t,
-                jax.random.fold_in(k_draw, 103),
-                compression=comp,
-            )
-            f_state = {**f_state, "buf": new_buf}
-            d_apply = stragglers.vec_to_tree(apply_vec, params)
-            params = jax.tree_util.tree_map(lambda a, g: a - g, params, d_apply)
+            with jax.named_scope("round.faults"):
+                u = jax.tree_util.tree_map(lambda a, b: a - b, params, new_params)
+                new_buf, apply_vec, _ = stragglers.async_step(
+                    faults,
+                    f_state["buf"],
+                    stragglers.tree_to_vec(u),
+                    t,
+                    jax.random.fold_in(k_draw, 103),
+                    compression=comp,
+                )
+                f_state = {**f_state, "buf": new_buf}
+                d_apply = stragglers.vec_to_tree(apply_vec, params)
+                params = jax.tree_util.tree_map(lambda a, g: a - g, params, d_apply)
         else:
             params = new_params
         # Sampler feedback: (N,)-vector scatter of the (C,) cohort norms,
         # constrained back onto the sampler's (N,)-shard layout so the
         # scatter result never materializes replicated at scale.
-        fb = sampler.shard_constrain(
-            jnp.zeros((n,), jnp.float32).at[sel.ids].add(
-                jnp.where(sel.valid, lam[sel.ids] * norms, 0.0)
+        with jax.named_scope("round.sampler_update"):
+            fb = sampler.shard_constrain(
+                jnp.zeros((n,), jnp.float32).at[sel.ids].add(
+                    jnp.where(sel.valid, lam[sel.ids] * norms, 0.0)
+                )
             )
-        )
-        s_state = sampler.update(s_state, draw, fb)
+            s_state = sampler.update(s_state, draw, fb)
         metrics = {
             "loss": loss,
             "cohort_size": jnp.sum(sel.valid.astype(jnp.int32)),

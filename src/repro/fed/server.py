@@ -201,9 +201,13 @@ def _build_client_step(task: Task, dataset, cfg: FedConfig):
         def get_batch(k):
             return dataset.client_batch(i, k, cfg.batch_size)
 
-        batches = jax.vmap(get_batch)(ks)
-        delta, loss = fed_client.local_update(params, task.loss, batches, cfg.local_lr)
-        return delta, loss, fed_client.update_norm(delta)
+        with jax.named_scope("round.gather"):
+            batches = jax.vmap(get_batch)(ks)
+        with jax.named_scope("round.local_train"):
+            delta, loss = fed_client.local_update(
+                params, task.loss, batches, cfg.local_lr
+            )
+            return delta, loss, fed_client.update_norm(delta)
 
     return one_client
 
@@ -220,7 +224,8 @@ def _build_all_clients(task: Task, dataset, cfg: FedConfig, lam):
     one_client = _build_client_step(task, dataset, cfg)
 
     def all_clients(params, key):
-        keys = _split_batch_keys(key, n, cfg.local_steps)
+        with jax.named_scope("round.gather"):
+            keys = _split_batch_keys(key, n, cfg.local_steps)
         deltas, losses, norms = jax.vmap(
             lambda i, ks: one_client(params, i, ks)
         )(jnp.arange(n), keys)
@@ -242,10 +247,9 @@ def _build_cohort_clients(task: Task, dataset, cfg: FedConfig):
     one_client = _build_client_step(task, dataset, cfg)
 
     def cohort_clients(params, key, cohort_ids):
-        keys = _split_batch_keys(key, n, cfg.local_steps)
-        return jax.vmap(lambda i, ks: one_client(params, i, ks))(
-            cohort_ids, keys[cohort_ids]
-        )
+        with jax.named_scope("round.gather"):
+            keys = _split_batch_keys(key, n, cfg.local_steps)[cohort_ids]
+        return jax.vmap(lambda i, ks: one_client(params, i, ks))(cohort_ids, keys)
 
     return cohort_clients
 
@@ -332,26 +336,32 @@ def _build_round_body(
 
         # Solve p~ once; reuse it for the draw AND the regret diagnostics
         # (the seed loop solved twice and diagnosed off draw.marginals).
-        p_marg = sampler.probabilities(s_state)
-        draw = sampler.sample_from(p_marg, k_sample)
+        with jax.named_scope("round.solve"):
+            p_marg = sampler.probabilities(s_state)
+        with jax.named_scope("round.draw"):
+            draw = sampler.sample_from(p_marg, k_sample)
         if avail_on:
             # Availability intersects the draw; composing q into the draw's
             # probabilities makes the plain client_weights call below the
             # availability-corrected (1/(q p)) estimator.  Distinct fold_in
             # streams (101/102/103) keep the sampler's own key untouched.
-            avail_mask, q_t, new_chain = stragglers.availability_step(
-                faults,
-                f_state.get("chain"),
-                t,
-                jax.random.fold_in(k_sample, 101),
-                n,
+            with jax.named_scope("round.faults"):
+                avail_mask, q_t, new_chain = stragglers.availability_step(
+                    faults,
+                    f_state.get("chain"),
+                    t,
+                    jax.random.fold_in(k_sample, 101),
+                    n,
+                )
+                avail_mask = sampler.shard_constrain(avail_mask)
+                q_t = sampler.shard_constrain(q_t)
+                draw = stragglers.available_draw(draw, avail_mask, q_t)
+                if "chain" in f_state:
+                    f_state = {**f_state, "chain": sampler.shard_constrain(new_chain)}
+        with jax.named_scope("round.select"):
+            weights = estimator.client_weights(
+                draw, lam, sampler.procedure, sampler.budget
             )
-            avail_mask = sampler.shard_constrain(avail_mask)
-            q_t = sampler.shard_constrain(q_t)
-            draw = stragglers.available_draw(draw, avail_mask, q_t)
-            if "chain" in f_state:
-                f_state = {**f_state, "chain": sampler.shard_constrain(new_chain)}
-        weights = estimator.client_weights(draw, lam, sampler.procedure, sampler.budget)
 
         deadline_dropped = jnp.zeros((), jnp.int32)
         if cfg.oracle_metrics:
@@ -363,13 +373,16 @@ def _build_round_body(
                 # nothing this round.  Survivor weights / surv keeps the
                 # estimate unbiased (E[1{survive}] = surv, independent of
                 # the draw).
-                lat = stragglers.latency_draw(
-                    faults, (n,), jax.random.fold_in(k_sample, 102)
-                )
-                late = jnp.logical_and(draw.mask, lat > jnp.float32(faults.deadline))
-                active = jnp.logical_and(draw.mask, ~late)
-                weights = jnp.where(late, 0.0, weights * jnp.float32(1.0 / surv))
-                deadline_dropped = jnp.sum(late.astype(jnp.int32))
+                with jax.named_scope("round.faults"):
+                    lat = stragglers.latency_draw(
+                        faults, (n,), jax.random.fold_in(k_sample, 102)
+                    )
+                    late = jnp.logical_and(
+                        draw.mask, lat > jnp.float32(faults.deadline)
+                    )
+                    active = jnp.logical_and(draw.mask, ~late)
+                    weights = jnp.where(late, 0.0, weights * jnp.float32(1.0 / surv))
+                    deadline_dropped = jnp.sum(late.astype(jnp.int32))
             feedback = feedback_full * active
             train_loss = jnp.sum(lam * losses)
             cohort_size = (
@@ -381,22 +394,27 @@ def _build_round_body(
                 # feedback norms are recomputed from the dequantized values
                 # (the regret signal is what the estimator actually saw), and
                 # with error feedback the applied estimate is d_hat + resid.
-                d_est, sq_err, norms_dq, new_resid = estimator.aggregate_compressed(
-                    deltas, weights, lam, comp, c_state.get("resid")
-                )
+                with jax.named_scope("round.aggregate"):
+                    d_est, sq_err, norms_dq, new_resid = (
+                        estimator.aggregate_compressed(
+                            deltas, weights, lam, comp, c_state.get("resid")
+                        )
+                    )
                 feedback_full = sampler.shard_constrain(lam * norms_dq)
                 feedback = feedback_full * active
                 if ef_on:
                     c_state = {"resid": new_resid}
             else:
                 # sq_err shares the one pass over the stacked (N, ...) deltas.
-                d_est, sq_err = estimator.aggregate_and_error(deltas, weights, lam)
+                with jax.named_scope("round.aggregate"):
+                    d_est, sq_err = estimator.aggregate_and_error(deltas, weights, lam)
         else:
             # Deployable: select C slots from the draw (fold_in keeps the
             # draw's key stream untouched) and train only those clients.
-            sel = fed_cohort.select_cohort(
-                draw.mask, weights, c_slots, jax.random.fold_in(k_sample, 1)
-            )
+            with jax.named_scope("round.select"):
+                sel = fed_cohort.select_cohort(
+                    draw.mask, weights, c_slots, jax.random.fold_in(k_sample, 1)
+                )
             overflow_dropped = sel.n_dropped
             deltas_c, losses_c, norms_c = cohort_clients(params, k_data, sel.ids)
             if deadline_on:
@@ -405,24 +423,26 @@ def _build_round_body(
                 # padding (weight/validity/feedback zeroed) and survivors are
                 # rescaled by 1/surv — the O(C*D) aggregation below is
                 # untouched (fed/cohort.py mask_selection).
-                lat_c = stragglers.latency_draw(
-                    faults, (c_slots,), jax.random.fold_in(k_sample, 102)
-                )
-                late_c = jnp.logical_and(
-                    sel.valid, lat_c > jnp.float32(faults.deadline)
-                )
-                sel = fed_cohort.mask_selection(sel, ~late_c, 1.0 / surv)
-                deadline_dropped = jnp.sum(late_c.astype(jnp.int32))
+                with jax.named_scope("round.faults"):
+                    lat_c = stragglers.latency_draw(
+                        faults, (c_slots,), jax.random.fold_in(k_sample, 102)
+                    )
+                    late_c = jnp.logical_and(
+                        sel.valid, lat_c > jnp.float32(faults.deadline)
+                    )
+                    sel = fed_cohort.mask_selection(sel, ~late_c, 1.0 / surv)
+                    deadline_dropped = jnp.sum(late_c.astype(jnp.int32))
             # Sampler feedback is an (N,)-vector scatter of a (C,) vector —
             # the sampler state is legitimately N-sized; only the (N, D)
             # delta pytree scatter is the scale problem.  (Compressed rounds
             # scatter the dequantized norms instead, below.)
             if not comp_on:
-                feedback = sampler.shard_constrain(
-                    fed_cohort.scatter_cohort(
-                        jnp.where(sel.valid, lam[sel.ids] * norms_c, 0.0), sel, n
+                with jax.named_scope("round.sampler_update"):
+                    feedback = sampler.shard_constrain(
+                        fed_cohort.scatter_cohort(
+                            jnp.where(sel.valid, lam[sel.ids] * norms_c, 0.0), sel, n
+                        )
                     )
-                )
             # Unbiased cohort estimate of the full weighted loss sum_i lam_i l_i.
             train_loss = jnp.sum(jnp.where(sel.valid, sel.weights * losses_c, 0.0))
             # The clients actually contacted (post-overflow-drop), not |S|.
@@ -432,24 +452,31 @@ def _build_round_body(
                 # contraction: bitwise equal to the oracle path when |S| <= C
                 # (inserted zero terms cannot change the partial sums), at
                 # O(N*D) memory cost.
-                deltas = fed_cohort.scatter_cohort(deltas_c, sel, n)
-                agg_weights = fed_cohort.scatter_cohort(sel.weights, sel, n)
-                d_est, sq_err = estimator.aggregate_and_error(deltas, agg_weights, lam)
+                with jax.named_scope("round.aggregate"):
+                    deltas = fed_cohort.scatter_cohort(deltas_c, sel, n)
+                    agg_weights = fed_cohort.scatter_cohort(sel.weights, sel, n)
+                    d_est, sq_err = estimator.aggregate_and_error(
+                        deltas, agg_weights, lam
+                    )
             elif comp_on:
                 # Compressed cohort width: the (C, D) stacked buffer lives at
                 # quantized width in HBM and is widened per VMEM tile inside
                 # the fused dequant-aggregate kernel.  Feedback norms come
                 # from the same pass (dequantized values); error feedback
                 # applies/updates the carried residual.
-                lam_c = jnp.where(sel.valid, lam[sel.ids], 0.0)
-                d_est, sq_err, norms_dq, new_resid = estimator.aggregate_compressed(
-                    deltas_c, sel.weights, lam_c, comp, c_state.get("resid")
-                )
-                feedback = sampler.shard_constrain(
-                    fed_cohort.scatter_cohort(
-                        jnp.where(sel.valid, lam[sel.ids] * norms_dq, 0.0), sel, n
+                with jax.named_scope("round.aggregate"):
+                    lam_c = jnp.where(sel.valid, lam[sel.ids], 0.0)
+                    d_est, sq_err, norms_dq, new_resid = (
+                        estimator.aggregate_compressed(
+                            deltas_c, sel.weights, lam_c, comp, c_state.get("resid")
+                        )
                     )
-                )
+                with jax.named_scope("round.sampler_update"):
+                    feedback = sampler.shard_constrain(
+                        fed_cohort.scatter_cohort(
+                            jnp.where(sel.valid, lam[sel.ids] * norms_dq, 0.0), sel, n
+                        )
+                    )
                 if ef_on:
                     c_state = {"resid": new_resid}
             else:
@@ -457,34 +484,36 @@ def _build_round_body(
                 # anywhere in the round (tests assert this on the jaxpr).
                 # Same value as the scatter path in exact arithmetic; allclose
                 # on hardware (fed/cohort.py "Aggregation width").
-                lam_c = jnp.where(sel.valid, lam[sel.ids], 0.0)
-                d_est, sq_err = estimator.aggregate_and_error_cohort(
-                    deltas_c, sel.weights, lam_c
-                )
+                with jax.named_scope("round.aggregate"):
+                    lam_c = jnp.where(sel.valid, lam[sel.ids], 0.0)
+                    d_est, sq_err = estimator.aggregate_and_error_cohort(
+                        deltas_c, sel.weights, lam_c
+                    )
         # sq_err is recorded only in oracle mode; the deployable branches'
         # error row is dead code and fused away.
         if async_on:
             # Buffered-async: the round's aggregate enters the carried (B, D)
             # stale-delta ring; the server applies only the staleness-
             # discounted deltas whose arrival round has come (possibly none).
-            u_vec = stragglers.tree_to_vec(d_est)
-            new_buf, apply_vec, _ = stragglers.async_step(
-                faults,
-                f_state["buf"],
-                u_vec,
-                t,
-                jax.random.fold_in(k_sample, 103),
-                compression=comp,
-            )
-            f_state = {**f_state, "buf": new_buf}
-            d_apply = stragglers.vec_to_tree(apply_vec, d_est)
-            params, opt_state = cfg.server_opt.apply(params, d_apply, opt_state)
-        else:
+            with jax.named_scope("round.faults"):
+                u_vec = stragglers.tree_to_vec(d_est)
+                new_buf, apply_vec, _ = stragglers.async_step(
+                    faults,
+                    f_state["buf"],
+                    u_vec,
+                    t,
+                    jax.random.fold_in(k_sample, 103),
+                    compression=comp,
+                )
+                f_state = {**f_state, "buf": new_buf}
+                d_est = stragglers.vec_to_tree(apply_vec, d_est)
+        with jax.named_scope("round.aggregate"):
             params, opt_state = cfg.server_opt.apply(params, d_est, opt_state)
 
         # The server only observes sampled feedback (Theorem 5.2's partial
         # feedback): masked to the cohort it actually contacted.
-        s_state = sampler.update(s_state, draw, feedback)
+        with jax.named_scope("round.sampler_update"):
+            s_state = sampler.update(s_state, draw, feedback)
 
         metrics = {
             "train_loss": train_loss,
@@ -510,12 +539,13 @@ def _build_round_body(
                 metrics["scores"] = feedback_full
         if eval_data is not None:
             do_eval = (t % cfg.eval_every == 0) | (t == cfg.rounds - 1)
-            metrics["accuracy"] = jax.lax.cond(
-                do_eval,
-                lambda p: task.accuracy(p, eval_data).astype(jnp.float32),
-                lambda p: jnp.full((), jnp.nan, jnp.float32),
-                params,
-            )
+            with jax.named_scope("round.eval"):
+                metrics["accuracy"] = jax.lax.cond(
+                    do_eval,
+                    lambda p: task.accuracy(p, eval_data).astype(jnp.float32),
+                    lambda p: jnp.full((), jnp.nan, jnp.float32),
+                    params,
+                )
         out = (params, opt_state, s_state)
         if fault_on:
             out = out + (f_state,)

@@ -62,10 +62,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+
+from repro import obs
 
 __all__ = [
     "TrainState",
@@ -316,8 +319,14 @@ def make_segment_fn(
     def place(state: TrainState) -> TrainState:
         return state if placement is None else jax.device_put(state, placement)
 
+    calls = itertools.count()
+
     def segment(state: TrainState, n_rounds: int) -> TrainState:
-        return scan_segment(place(state), data, n_rounds)
+        call = next(calls)
+        with obs.span("train.place", call=call):
+            state = place(state)
+        with obs.span("train.dispatch", call=call, rounds=n_rounds):
+            return scan_segment(state, data, n_rounds)
 
     segment._cache_size = scan_segment._cache_size
     segment.lower = lambda state, n_rounds: scan_segment.lower(
@@ -388,9 +397,11 @@ def run_segmented(
         state = segment_fn(state, n)
         done += n
         if manager is not None:
-            manager.save(state, step=done)
+            with obs.span("train.ckpt_save", step=done):
+                manager.save(state, step=done)
             if publish is not None:
-                publish(state, done)
+                with obs.span("train.publish", step=done):
+                    publish(state, done)
         if on_segment is not None:
             on_segment(state, done)
         n_segments += 1

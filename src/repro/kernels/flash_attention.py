@@ -130,4 +130,5 @@ def flash_attention(
             pltpu.VMEM((bq, 128), jnp.float32),  # running denom
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

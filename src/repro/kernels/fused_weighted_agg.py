@@ -163,6 +163,7 @@ def fused_weighted_agg(
         ],
         scratch_shapes=[pltpu.VMEM((c, 128), jnp.float32)],
         interpret=interpret,
+        name="fused_weighted_agg",
     )(g, w[:, None])
     return d_out[0], sq[:, 0]
 
@@ -201,6 +202,7 @@ def fused_multi_weighted_agg(
         out_specs=pl.BlockSpec((m, bd), lambda ic: (0, ic)),
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
         interpret=interpret,
+        name="fused_multi_weighted_agg",
     )(g, w)
 
 
@@ -265,6 +267,7 @@ def fused_cohort_agg_and_error(
             jax.ShapeDtypeStruct((1, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_cohort_agg_and_error",
     )(g, w2)
     return d_out[0], err[0, 0]
 
@@ -353,5 +356,6 @@ def fused_dequant_cohort_agg(
             jax.ShapeDtypeStruct((c, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_dequant_cohort_agg",
     )(q, scales, w2)
     return d_out[0], err[0, 0], sqn[:, 0]

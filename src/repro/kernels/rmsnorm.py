@@ -43,4 +43,5 @@ def rmsnorm(
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x, scale[None, :])

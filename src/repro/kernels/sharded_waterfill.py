@@ -115,5 +115,6 @@ def waterfill_level_stats(
         out_shape=jax.ShapeDtypeStruct((3, l_pad, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((3, l_pad, q), jnp.float32)],
         interpret=interpret,
+        name="waterfill_level_stats",
     )(s2, lv2)
     return out[0, :l, 0], out[1, :l, 0], out[2, :l, 0]

@@ -103,4 +103,5 @@ def ssd_scan(
         out_shape=jax.ShapeDtypeStruct((bh, s, hd), x.dtype),
         scratch_shapes=[pltpu.VMEM((hd, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(x, da_pad, b, c)

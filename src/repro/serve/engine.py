@@ -35,6 +35,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import transformer
 
 __all__ = ["ServeEngine"]
@@ -111,7 +112,6 @@ class ServeEngine:
         self._params = jax.device_put(params)
         self._param_treedef = jax.tree_util.tree_structure(params)
         self._param_avals = _leaf_avals(params)
-        self.swaps = 0
 
         # In-flight generation state (None until start()).
         self._tok = None
@@ -119,8 +119,15 @@ class ServeEngine:
         self._index = 0
         self._out: list = []
 
-        # Decode-side accounting (prefill excluded: tokens/sec is the decode
-        # steady state the bench gates).
+        # Counters, plain host ints (``counters()`` returns them all):
+        # batches prefilled, waits on the device, swaps installed and swaps
+        # refused for treedef or aval drift.  Decode-side accounting
+        # (prefill excluded: tokens/sec is the decode steady state the bench
+        # gates) is assignable, so a caller can reset it.
+        self.prefills = 0
+        self.host_syncs = 0
+        self.swaps = 0
+        self.swaps_rejected = 0
         self.decode_tokens = 0
         self.decode_seconds = 0.0
 
@@ -174,9 +181,11 @@ class ServeEngine:
                 f"prompt_len {prompts.shape[1]} must leave decode room under "
                 f"max_seq={self.max_seq}"
             )
-        tok, _, caches = self._prefill(
-            self._params, prompts, self._next_key(), self._temp()
-        )
+        self.prefills += 1
+        with obs.span("serve.start", batch=self.prefills):
+            tok, _, caches = self._prefill(
+                self._params, prompts, self._next_key(), self._temp()
+            )
         self._tok, self._caches = tok, caches
         self._index = int(prompts.shape[1])
         self._out = [tok]
@@ -187,28 +196,31 @@ class ServeEngine:
 
         Returns the number of steps executed; accumulates decode-side
         wall-clock for ``tokens_per_sec``."""
-        if self._tok is None:
-            raise RuntimeError("no in-flight batch; call start(prompts) first")
-        n = min(int(n), self.capacity)
-        if n <= 0:
-            return 0
-        t0 = time.perf_counter()
-        tok, caches = self._tok, self._caches
-        for _ in range(n):
-            tok, _, caches = self._decode(
-                self._params,
-                tok,
-                caches,
-                jnp.asarray(self._index, jnp.int32),
-                self._next_key(),
-                self._temp(),
-            )
-            self._index += 1
-            self._out.append(tok)
-        jax.block_until_ready(tok)
-        self._tok, self._caches = tok, caches
-        self.decode_seconds += time.perf_counter() - t0
-        self.decode_tokens += n * self.batch
+        with obs.span("serve.step", batch=self.prefills, index=self._index):
+            if self._tok is None:
+                raise RuntimeError("no in-flight batch; call start(prompts) first")
+            n = min(int(n), self.capacity)
+            if n <= 0:
+                return 0
+            t0 = time.perf_counter()
+            tok, caches = self._tok, self._caches
+            for _ in range(n):
+                with obs.span("serve.step.prep"):
+                    index = jnp.asarray(self._index, jnp.int32)
+                    key = self._next_key()
+                    temperature = self._temp()
+                with obs.span("serve.step.dispatch"):
+                    tok, _, caches = self._decode(
+                        self._params, tok, caches, index, key, temperature
+                    )
+                self._index += 1
+                self._out.append(tok)
+            with obs.span("serve.step.wait"):
+                jax.block_until_ready(tok)
+            self.host_syncs += 1
+            self._tok, self._caches = tok, caches
+            self.decode_seconds += time.perf_counter() - t0
+            self.decode_tokens += n * self.batch
         return n
 
     def generated(self) -> jax.Array:
@@ -220,6 +232,17 @@ class ServeEngine:
     def tokens_per_sec(self) -> float:
         return self.decode_tokens / max(self.decode_seconds, 1e-9)
 
+    def counters(self) -> dict:
+        """The engine's counters, for logs and operators."""
+        return {
+            "prefills": self.prefills,
+            "host_syncs": self.host_syncs,
+            "swaps": self.swaps,
+            "swaps_rejected": self.swaps_rejected,
+            "decode_tokens": self.decode_tokens,
+            "decode_seconds": self.decode_seconds,
+        }
+
     # -- the hot swap --------------------------------------------------------
     def swap_params(self, new_params) -> None:
         """Install candidate weights between decode steps.
@@ -229,22 +252,25 @@ class ServeEngine:
         instead of poisoning the jit cache with a second entry.  In-flight
         sequences are untouched — caches, positions, and last tokens carry
         straight into the next decode step under the new weights."""
-        treedef = jax.tree_util.tree_structure(new_params)
-        if treedef != self._param_treedef:
-            raise ValueError(
-                f"swap_params: param treedef changed\n  pinned: "
-                f"{self._param_treedef}\n  candidate: {treedef}"
-            )
-        for (path, shape, dtype), (_, got_shape, got_dtype) in zip(
-            self._param_avals, _leaf_avals(new_params)
-        ):
-            if (shape, dtype) != (got_shape, got_dtype):
+        with obs.span("serve.swap", swap=self.swaps):
+            treedef = jax.tree_util.tree_structure(new_params)
+            if treedef != self._param_treedef:
+                self.swaps_rejected += 1
                 raise ValueError(
-                    f"swap_params: param aval drift at {path}: pinned "
-                    f"{shape}/{dtype}, candidate {got_shape}/{got_dtype} — "
-                    "a swap must match the pinned signature exactly"
+                    f"swap_params: param treedef changed\n  pinned: "
+                    f"{self._param_treedef}\n  candidate: {treedef}"
                 )
-        self._params = jax.device_put(new_params)
+            for (path, shape, dtype), (_, got_shape, got_dtype) in zip(
+                self._param_avals, _leaf_avals(new_params)
+            ):
+                if (shape, dtype) != (got_shape, got_dtype):
+                    self.swaps_rejected += 1
+                    raise ValueError(
+                        f"swap_params: param aval drift at {path}: pinned "
+                        f"{shape}/{dtype}, candidate {got_shape}/{got_dtype} — "
+                        "a swap must match the pinned signature exactly"
+                    )
+            self._params = jax.device_put(new_params)
         self.swaps += 1
 
     # -- lint handles --------------------------------------------------------

@@ -36,6 +36,8 @@ class ServeSummary:
     swaps: int
     last_step: int
     batches_served: int
+    host_syncs: int  # the engine's waits on the device
+    swaps_rejected: int  # swaps the engine refused for signature drift
 
     def render(self) -> str:
         # Machine-readable: the CI serve-smoke job greps this exact shape.
@@ -43,7 +45,8 @@ class ServeSummary:
             f"serve summary: promotions={self.promotions} "
             f"rollbacks={self.rollbacks} tokens={self.tokens} "
             f"tokens_per_sec={self.tokens_per_sec:.1f} swaps={self.swaps} "
-            f"last_step={self.last_step} batches={self.batches_served}"
+            f"last_step={self.last_step} batches={self.batches_served} "
+            f"host_syncs={self.host_syncs} swaps_rejected={self.swaps_rejected}"
         )
 
 
@@ -129,4 +132,6 @@ class ServeSession:
             swaps=engine.swaps,
             last_step=watcher.seen_step,
             batches_served=batches,
+            host_syncs=engine.host_syncs,
+            swaps_rejected=engine.swaps_rejected,
         )

@@ -132,3 +132,47 @@ def test_estimator_runs_kernel_at_any_width_on_tpu(one_chip, monkeypatch, path):
         comp = CompressionSpec(delta_dtype="int8")
         fn = lambda u, w, lam: estimator.aggregate_compressed(u, w, lam, comp)
     assert "tpu_custom_call" in _hlo(fn, updates, spec((c,)), spec((c,)))
+
+
+def _kernel_args(name, spec):
+    if name == "rmsnorm":
+        return (spec((256, 256)), spec((256,))), {}
+    if name == "flash_attention":
+        return (spec((2, 256, 128)),) * 3, {}
+    if name == "waterfill_level_stats":
+        return (spec((4096,)), spec((128,)), spec((128,))), {}
+    if name == "fused_dequant_cohort_agg":
+        return (spec((2, 16384), jnp.int8), spec((2, 128)), spec((2,)), spec((2,))), {}
+    if name == "fused_multi_weighted_agg":
+        return (spec((2, 4096)), spec((2, 2))), {"block_d": 2048}
+    return (spec((8, 4096)), spec((8,))) + ((spec((8,)),) if name.endswith("error") else ()), {
+        "block_d": 2048}
+
+
+KERNELS = {
+    "rmsnorm": "repro.kernels.rmsnorm",
+    "flash_attention": "repro.kernels.flash_attention",
+    "waterfill_level_stats": "repro.kernels.sharded_waterfill",
+    "fused_weighted_agg": "repro.kernels.fused_weighted_agg",
+    "fused_multi_weighted_agg": "repro.kernels.fused_weighted_agg",
+    "fused_cohort_agg_and_error": "repro.kernels.fused_weighted_agg",
+    "fused_dequant_cohort_agg": "repro.kernels.fused_weighted_agg",
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_names_its_pallas_call(one_chip, name):
+    """Each ``pallas_call`` is named after its kernel: the Mosaic call carries
+    that ``kernel_name``, and the trace shows the compiled instruction under
+    it, where the benchmark's readers match it (``waterfill``,
+    ``dequant_cohort``).  ``ssd_scan`` runs in interpret mode only: its
+    ``cumsum`` has no TPU lowering."""
+    fn = getattr(importlib.import_module(KERNELS[name]), name)
+    spec = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    args, kw = _kernel_args(name, spec)
+    lowered = jax.jit(lambda *a: fn(*a, **kw)).lower(*args)
+    assert f'kernel_name = "{name}"' in lowered.as_text()
+    calls = [line.split(" = ", 1)[0].split()[-1].lstrip("%")
+             for line in lowered.compile().as_text().splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert calls and all(c.rsplit(".", 1)[0] == name for c in calls), calls
