@@ -44,15 +44,26 @@ class FederatedDataset:
         idx = jax.random.randint(key, (batch_size,), 0, self.sizes[client])
         return self.features[client, idx], self.labels[client, idx]
 
+    def rows(self, ids: jax.Array) -> "FederatedDataset":
+        """The clients ``ids`` as a dataset of their own: whole rows gathered
+        along the client axis, so row ``j`` is client ``ids[j]``.
+
+        A round gathers its cohort's rows once and samples each slot's batches
+        from them (``rows(ids).client_batch(j, ...)``, the same batches as
+        ``client_batch(ids[j], ...)``).  Sampled straight from the full
+        dataset under a per-slot ``vmap``, the batches are one point gather
+        over the first two axes, for which the TPU compiler relayouts the
+        whole (N, S_max, ...) array into a padded temporary on every call."""
+        return FederatedDataset(
+            features=self.features[ids], labels=self.labels[ids], sizes=self.sizes[ids]
+        )
+
     def batch_all_clients(self, key: jax.Array, batch_size: int):
         """(N, B, ...) batches for vmapped full-cohort simulation."""
         keys = jax.random.split(key, self.n_clients)
-
-        def one(client, k):
-            idx = jax.random.randint(k, (batch_size,), 0, self.sizes[client])
-            return self.features[client, idx], self.labels[client, idx]
-
-        return jax.vmap(one)(jnp.arange(self.n_clients), keys)
+        return jax.vmap(lambda client, k: self.client_batch(client, k, batch_size))(
+            jnp.arange(self.n_clients), keys
+        )
 
 
 def synthetic_classification(

@@ -345,17 +345,20 @@ def _build_scan_body(cfg, spec, sampler, dataset, mesh, constrain):
             return x
 
     def gather_cohort(sel, k_data):
-        """(C, R, B, ...) device gather; padding slots zeroed (inert)."""
+        """(C, R, B, ...) device gather; padding slots zeroed (inert).  The
+        cohort's rows are gathered once, then each slot samples its own row
+        (``FederatedDataset.rows``)."""
+        rows = dataset.rows(sel.ids)
 
-        def one(cid):
+        def one(slot, cid):
             keys = jax.random.split(
                 jax.random.fold_in(k_data, cid), spec.local_steps
             )
             return jax.vmap(
-                lambda kr: dataset.client_batch(cid, kr, spec.local_batch)
+                lambda kr: rows.client_batch(slot, kr, spec.local_batch)
             )(keys)
 
-        feats, labs = jax.vmap(one)(sel.ids)
+        feats, labs = jax.vmap(one)(jnp.arange(sel.ids.shape[0]), sel.ids)
 
         def zero_pad(leaf):
             keep = sel.valid.reshape((-1,) + (1,) * (leaf.ndim - 1))

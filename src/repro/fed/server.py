@@ -191,15 +191,16 @@ class History:
         return out
 
 
-def _build_client_step(task: Task, dataset, cfg: FedConfig):
-    """One client's local update: (params, client id, (R, 2) batch keys) ->
-    (delta, loss, update norm).  Shared by the oracle and deployable paths so
+def _build_client_step(task: Task, cfg: FedConfig):
+    """One client's local update: (params, rows, row index, (R, 2) batch keys)
+    -> (delta, loss, update norm), ``rows`` a ``FederatedDataset`` holding the
+    client at that row.  Shared by the oracle and deployable paths so
     their per-client numerics cannot drift apart — cross-mode bit-identity
     (tests/test_scan_server.py) depends on this being a single definition."""
 
-    def one_client(params, i, ks):
+    def one_client(params, rows, i, ks):
         def get_batch(k):
-            return dataset.client_batch(i, k, cfg.batch_size)
+            return rows.client_batch(i, k, cfg.batch_size)
 
         with jax.named_scope("round.gather"):
             batches = jax.vmap(get_batch)(ks)
@@ -221,13 +222,13 @@ def _build_all_clients(task: Task, dataset, cfg: FedConfig, lam):
     """All-clients local-update step (oracle mode): vmapped over clients."""
 
     n = dataset.n_clients
-    one_client = _build_client_step(task, dataset, cfg)
+    one_client = _build_client_step(task, cfg)
 
     def all_clients(params, key):
         with jax.named_scope("round.gather"):
             keys = _split_batch_keys(key, n, cfg.local_steps)
         deltas, losses, norms = jax.vmap(
-            lambda i, ks: one_client(params, i, ks)
+            lambda i, ks: one_client(params, dataset, i, ks)
         )(jnp.arange(n), keys)
         feedback = lam * norms  # pi_t(i) = lambda_i ||g_i||
         return deltas, losses, feedback
@@ -241,15 +242,19 @@ def _build_cohort_clients(task: Task, dataset, cfg: FedConfig):
     ``_build_all_clients`` and then gathered by client id, so a cohort
     client's batches — and therefore its delta/loss/norm — are bit-identical
     to what the oracle path computes for that client (key material is O(N)
-    but cheap; the O(N * local-train) compute is what this path removes)."""
+    but cheap; the O(N * local-train) compute is what this path removes).
+    The cohort's data rows are gathered once, outside the per-slot ``vmap``
+    (``FederatedDataset.rows``): the dataset is never relayouted."""
 
     n = dataset.n_clients
-    one_client = _build_client_step(task, dataset, cfg)
+    one_client = _build_client_step(task, cfg)
 
     def cohort_clients(params, key, cohort_ids):
         with jax.named_scope("round.gather"):
             keys = _split_batch_keys(key, n, cfg.local_steps)[cohort_ids]
-        return jax.vmap(lambda i, ks: one_client(params, i, ks))(cohort_ids, keys)
+            rows = dataset.rows(cohort_ids)
+        slots = jnp.arange(cohort_ids.shape[0])
+        return jax.vmap(lambda j, ks: one_client(params, rows, j, ks))(slots, keys)
 
     return cohort_clients
 

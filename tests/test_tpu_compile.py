@@ -9,6 +9,7 @@ compiler library.
 """
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core import estimator
+from repro.data import FederatedDataset
+from repro.fed import FedConfig, logistic_regression
+from repro.fed import server as fed_server
 from repro.models import transformer
 
 fwa = importlib.import_module("repro.kernels.fused_weighted_agg")
@@ -132,6 +136,38 @@ def test_estimator_runs_kernel_at_any_width_on_tpu(one_chip, monkeypatch, path):
         comp = CompressionSpec(delta_dtype="int8")
         fn = lambda u, w, lam: estimator.aggregate_compressed(u, w, lam, comp)
     assert "tpu_custom_call" in _hlo(fn, updates, spec((c,)), spec((c,)))
+
+
+@pytest.mark.parametrize("precision", ["highest", None])
+def test_cohort_step_leaves_the_dataset_in_place(one_chip, precision):
+    """The deployable cohort step of the million-client logreg run gathers
+    its cohort's rows and never relayouts the dataset.  Sampled per slot
+    straight from the (N, 8, 60) dataset, the batches are one point gather
+    that wants another layout, so every call copied the whole dataset into a
+    temporary padded to 128 features: 4.1 GB at the run's "highest" matmul
+    precision, 2.0 GB as bfloat16 at the default one."""
+    n, s, dim = 1_000_000, 8, 60
+    spec = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    task = logistic_regression(dim=dim, n_classes=10)
+    cfg = FedConfig(budget=64, cohort=SAMPLER_COHORT, local_steps=1, batch_size=8,
+                    local_lr=0.01, oracle_metrics=False)
+    ds = FederatedDataset(features=spec((n, s, dim)), labels=spec((n, s), jnp.int32),
+                          sizes=spec((n,), jnp.int32))
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype), jax.eval_shape(task.init, jax.random.PRNGKey(0)))
+
+    def step(ds, params, key, cohort_ids):
+        return fed_server._build_cohort_clients(task, ds, cfg)(params, key, cohort_ids)
+
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(step).lower(
+            ds, params, spec((2,), jnp.uint32), spec((SAMPLER_COHORT,), jnp.int32)).compile()
+    # "%name = f32[N,8,60]{layout} opcode(": what produces a whole-dataset value
+    produced = re.compile(rf"%\S+ = \w+\[{n},{s},{dim}\](?:\{{[^}}]*\}})? ([\w-]+)\(")
+    made = [m.group(0) for m in map(produced.search, compiled.as_text().splitlines())
+            if m and m.group(1) != "parameter"]
+    assert not made, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 def _kernel_args(name, spec):
