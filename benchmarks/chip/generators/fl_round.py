@@ -28,8 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import counts, datasets, weights
-from ..references import fedavg, llama, logreg
+from .. import counts, datasets, references, weights
+from ..references import fedavg, precision
 
 __all__ = ["Cell"]
 
@@ -43,6 +43,7 @@ class Cell:
     def __init__(self, cfg: dict, traffic: dict, seed: int):
         self.cfg, self.traffic, self.seed = cfg, traffic, int(seed)
         self.stack = cfg["stack"]
+        self.family = references.family(cfg)
         self.loss_key = "loss" if self.stack == "zoo" else "train_loss"
 
     # -- the program --------------------------------------------------------
@@ -90,14 +91,7 @@ class Cell:
             from repro.launch.mesh import make_host_mesh
 
             cfg = built.arch_config
-            for mine, theirs in (("hidden_size", cfg.d_model), ("num_hidden_layers", cfg.n_layers),
-                                 ("num_attention_heads", cfg.n_heads), ("head_dim", cfg.hd),
-                                 ("num_key_value_heads", cfg.n_kv_heads),
-                                 ("intermediate_size", cfg.d_ff), ("vocab_size", cfg.vocab),
-                                 ("rms_norm_eps", cfg.norm_eps), ("rope_theta", cfg.rope_theta)):
-                if self.cfg[mine] != theirs:
-                    raise ValueError(f"configuration file {mine}={self.cfg[mine]} but the "
-                                     f"program runs {theirs}")
+            self.family.check_program(self.cfg, cfg)
             segment, make_state = build_fed_scan_segment(
                 cfg, built.round_spec, built.sampler, built.dataset, mesh=make_host_mesh())
             state = make_state(params, built.sampler.init(), key, spec.federation.rounds)
@@ -181,14 +175,7 @@ class Cell:
         """Counts that per-layer readers divide by device time."""
         t, c = self.traffic, self.cfg
         fed = t["federation"]
-        out = {"rounds": raw["rounds"]}
-        if c["model"] == "llama":
-            seq = t["data"]["seq_len"]
-            tokens = fed["cohort"] * fed["local_steps"] * fed["batch_size"] * seq
-            out["train_flops_per_round"] = counts.llama_train_flops(c, tokens, seq)
-        else:
-            samples = fed["cohort"] * fed["local_steps"] * fed["batch_size"]
-            out["train_flops_per_round"] = counts.logreg_train_flops(c, samples)
+        out = {"rounds": raw["rounds"], "train_flops_per_round": self.family.train_flops(c, t)}
         comp = t.get("compression")
         if comp:
             d = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(self.state.params))
@@ -237,7 +224,7 @@ class Cell:
                     if fault == "token":
                         tok = tok.at[0, 0].set((tok[0, 0] + 1) % c["vocab_size"])
                     pf = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), p)
-                    last, g = llama.grad(pf, tok, tgt, m_items, mode)
+                    last, g = self.family.grad(pf, tok, tgt, m_items, mode)
                     del pf
                     p = jax.tree_util.tree_map(
                         lambda w, gr: (w.astype(jnp.float32)
@@ -275,8 +262,9 @@ class Cell:
                         x, y = x[: batch // 2], y[: batch // 2]
                     if fault == "token":
                         y = y.at[0].set((y[0] + 1) % c["n_classes"])
-                    last, g = logreg.grad(p, x, y, mode)
-                    p = jax.tree_util.tree_map(lambda w, gr: llama.cast(w - lr * gr, mode), p, g)
+                    last, g = self.family.grad(p, x, y, mode)
+                    p = jax.tree_util.tree_map(lambda w, gr: precision.cast(w - lr * gr, mode),
+                                               p, g)
                 if fault == "unchanged":
                     p = params
                 return jax.tree_util.tree_map(jnp.subtract, params, p), last

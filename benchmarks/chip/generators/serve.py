@@ -24,8 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import counts, datasets, weights
-from ..references import llama
+from .. import datasets, references, weights
 
 __all__ = ["Cell"]
 
@@ -46,6 +45,7 @@ class Cell:
     def __init__(self, cfg: dict, traffic: dict, seed: int):
         self.cfg, self.traffic, self.seed = cfg, traffic, int(seed)
         self.max_seq = traffic["prompt_len"] + traffic["new_tokens"]
+        self.family = references.family(cfg)
 
     def prepare(self):
         from repro.configs import get_config
@@ -126,11 +126,9 @@ class Cell:
 
     def info(self, raw):
         t, c = self.traffic, self.cfg
-        b, L, n = t["batch"], t["prompt_len"], t["new_tokens"]
-        per_batch = counts.llama_forward_flops(c, b, L) + sum(
-            counts.llama_decode_flops(c, b, L + i) for i in range(n))
+        per_batch = self.family.serve_flops(c, t["batch"], t["prompt_len"], t["new_tokens"])
         return {"serve_flops": per_batch * raw["batches"], "batches": raw["batches"],
-                "decode_steps": raw["batches"] * n}
+                "decode_steps": raw["batches"] * t["new_tokens"]}
 
     def release(self):
         del self.engine, self.params
@@ -167,15 +165,16 @@ class Cell:
         pf = [jax.tree_util.tree_map(lambda x: x.astype(jnp.float32),
                                      weights.make(self.cfg, self.seed, s)) for s in _STREAMS]
         m_items, L = _items(self.cfg), t["prompt_len"]
+        gaps = self.family.served_gaps
         worst = 0.0
         for r in range(tokens.shape[0]):
             tok = tokens[r:r + 1]
             use_q = jnp.asarray(scheds[r] == 1)
             alt = tok
             if control:
-                _, _, top = llama.served_gaps(pf[0], pf[1], use_q, tok, tok, m_items, control)
+                _, _, top = gaps(pf[0], pf[1], use_q, tok, tok, m_items, control)
                 alt = jnp.concatenate([tok[:, :1], top], axis=1)
-            g, ga, _ = llama.served_gaps(pf[0], pf[1], use_q, tok, alt, m_items, mode)
+            g, ga, _ = gaps(pf[0], pf[1], use_q, tok, alt, m_items, mode)
             g = ga if control else g
             worst = max(worst, float(jnp.max(g[:, L - 1:])))
         return {"logit_gap": worst}

@@ -1,6 +1,5 @@
 """Model FLOP utilisation of serving: the prefill and decode FLOPs of every
-batch served in the traced window (``counts.llama_forward_flops`` and
-``llama_decode_flops``: 2 x matmul params per token plus attention), over the
+batch served in the traced window (the family's ``serve_flops``), over the
 window times the chip's bf16 peak."""
 
 

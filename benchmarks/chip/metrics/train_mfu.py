@@ -1,8 +1,8 @@
 """Model FLOP utilisation of the federated round, the whole step's share of
 the chip's peak: the forward and backward FLOPs of every cohort slot's local
-steps (``counts.llama_train_flops`` or ``logreg_train_flops``, recomputation
-not counted) times the rounds completed in the traced window, over the
-window times the chip's bf16 peak."""
+steps (the family's ``train_flops``, recomputation not counted) times the
+rounds completed in the traced window, over the window times the chip's
+bf16 peak."""
 
 
 def read(ctx):

@@ -1,11 +1,11 @@
-"""Plain llama-style decoder: forward, loss and gradient in float32.
+"""The llama-style decoder family: weights, FLOP counts, the program check,
+and a plain forward, loss and gradient in float32.
 
-Follows the parameter layout that the system under test is fed (the
-benchmark's own weights, ``weights.make``): ``embed`` (V, d) tied as the LM
-head, ``final_norm`` (d,), and one dict per layer kind stacked over layers
-in ``stacks[0]`` with ``ln1``/``ln2`` (d,), ``attn`` {``wq`` (d, H hd),
-``wk``/``wv`` (d, KV hd), ``wo`` (H hd, d)} and ``mlp`` {``up``/``gate``
-(d, f), ``down`` (f, d)}.
+Follows the parameter layout that the system under test is fed
+(``weights``): ``embed`` (V, d) tied as the LM head, ``final_norm`` (d,),
+and one dict per layer kind stacked over layers in ``stacks[0]`` with
+``ln1``/``ln2`` (d,), ``attn`` {``wq`` (d, H hd), ``wk``/``wv`` (d, KV hd),
+``wo`` (H hd, d)} and ``mlp`` {``up``/``gate`` (d, f), ``down`` (f, d)}.
 
 The equations, and where they depart from the published SmolLM (Llama):
 
@@ -17,11 +17,11 @@ The equations, and where they depart from the published SmolLM (Llama):
   ``h // (H / KV)``; causal softmax in float32 with scale ``hd^-1/2``.
 * MLP: ``down(up(x) * silu(gate(x)))``.
 
-Every product goes through ``_mm``/``_einsum``, which take the precision:
-``"f32"`` is float32 at ``Precision.HIGHEST``; a lower mode rounds each
-operand to that type first (the control of the correctness check).  Layers
-run under ``jax.checkpoint`` in a ``lax.scan`` so a 32-layer gradient fits
-beside the weights.
+Every product goes through ``_mm``/``_einsum``, which take the precision
+(``precision.cast``): ``"f32"`` is float32 at ``Precision.HIGHEST``; a lower
+mode rounds each operand to that type first (the control of the correctness
+check).  Layers run under ``jax.checkpoint`` in a ``lax.scan`` so a 32-layer
+gradient fits beside the weights.
 """
 from __future__ import annotations
 
@@ -30,17 +30,116 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["cast", "loss", "grad", "served_gaps"]
+from .precision import HI, cast
 
-HI = jax.lax.Precision.HIGHEST
+__all__ = ["weights", "train_flops", "serve_flops", "check_program", "matmul_params",
+           "loss", "grad", "served_gaps"]
 
-_LOWER = {"f32": None, "bf16": jnp.bfloat16, "fp8": jnp.float8_e4m3fn}
+
+def _items(cfg: dict) -> tuple:
+    return tuple(sorted((k, v) for k, v in cfg.items()
+                        if isinstance(v, (int, float, str, bool))))
 
 
-def cast(x, mode: str):
-    """Round ``x`` to the precision ``mode`` computes in, back in float32."""
-    low = _LOWER[mode]
-    return x if low is None else x.astype(low).astype(jnp.float32)
+@functools.partial(jax.jit, static_argnums=(1,))
+def _weights(key, items):
+    m = dict(items)
+    d, f, L, V = m["hidden_size"], m["intermediate_size"], m["num_hidden_layers"], m["vocab_size"]
+    hd, h, kv = m["head_dim"], m["num_attention_heads"], m["num_key_value_heads"]
+    dt = jnp.dtype(m["torch_dtype"])
+    ks = iter(jax.random.split(key, 16))
+
+    def uni(shape, fan_in):
+        bound = fan_in ** -0.5
+        return jax.random.uniform(next(ks), shape, jnp.float32, -bound, bound).astype(dt)
+
+    def norm_scale(shape):
+        return (0.05 * jax.random.normal(next(ks), shape)).astype(dt)
+
+    layers = {
+        "ln1": norm_scale((L, d)),
+        "attn": {"wq": uni((L, d, h * hd), d), "wk": uni((L, d, kv * hd), d),
+                 "wv": uni((L, d, kv * hd), d), "wo": uni((L, h * hd, d), h * hd)},
+        "ln2": norm_scale((L, d)),
+        "mlp": {"up": uni((L, d, f), d), "down": uni((L, f, d), f), "gate": uni((L, d, f), d)},
+    }
+    return {
+        "embed": (0.02 * jax.random.normal(next(ks), (V, d))).astype(dt),
+        "final_norm": norm_scale((d,)),
+        "stacks": [layers],
+    }
+
+
+def weights(cfg: dict, key):
+    """Projections uniform in +-fan_in^-1/2, embedding N(0, 0.02^2), norm
+    scales N(0, 0.05^2), in the configuration's ``torch_dtype``."""
+    return _weights(key, _items(cfg))
+
+
+# -- FLOP counts, from the configuration's sizes ---------------------------
+
+
+def matmul_params(m: dict) -> int:
+    """Weights that take part in a matmul per token: the attention and MLP
+    projections of every layer and the LM head (tied or not).  The embedding
+    lookup is a gather and costs no FLOPs."""
+    d, f, L, V = m["hidden_size"], m["intermediate_size"], m["num_hidden_layers"], m["vocab_size"]
+    hd = m["head_dim"]
+    h, kv = m["num_attention_heads"], m["num_key_value_heads"]
+    per_layer = d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
+    return L * per_layer + d * V
+
+
+def _attn_flops_per_token(m: dict, ctx: int) -> int:
+    """Forward score and value products of one token against ``ctx``
+    positions, every layer: 2 matmuls x 2 FLOPs x heads x head size x ctx."""
+    return 4 * m["num_hidden_layers"] * m["num_attention_heads"] * m["head_dim"] * ctx
+
+
+def train_flops(m: dict, traffic: dict) -> float:
+    """Forward and backward over one round's tokens (cohort x local steps x
+    batch x ``seq_len``): 6 x matmul params per token plus 3 x the forward
+    attention products at the full sequence length (the PaLM appendix B
+    count).  Recomputation under ``remat`` is not counted."""
+    fed, seq = traffic["federation"], traffic["data"]["seq_len"]
+    tokens = fed["cohort"] * fed["local_steps"] * fed["batch_size"] * seq
+    return float(tokens) * (6 * matmul_params(m) + 3 * _attn_flops_per_token(m, seq))
+
+
+def _forward_flops(m: dict, batch: int, seq: int) -> float:
+    """Prefill: forward over ``batch`` prompts of ``seq`` tokens, attention
+    over the causal half of the (seq, seq) products."""
+    tokens = batch * seq
+    return float(tokens) * 2 * matmul_params(m) + batch * _attn_flops_per_token(
+        m, seq) * seq / 2
+
+
+def _decode_flops(m: dict, batch: int, ctx: int) -> float:
+    """One decode step of ``batch`` sequences with ``ctx`` cached positions."""
+    return float(batch) * (2 * matmul_params(m) + _attn_flops_per_token(m, ctx))
+
+
+def serve_flops(m: dict, batch: int, prompt_len: int, new_tokens: int) -> float:
+    """One batch: the prefill of ``batch`` prompts, then ``new_tokens``
+    decode steps, step ``i`` over ``prompt_len + i`` cached positions."""
+    return _forward_flops(m, batch, prompt_len) + sum(
+        _decode_flops(m, batch, prompt_len + i) for i in range(new_tokens))
+
+
+def check_program(m: dict, arch) -> None:
+    """Raise where the program's ``ArchConfig`` runs other sizes than the
+    configuration file states."""
+    for mine, theirs in (("hidden_size", arch.d_model), ("num_hidden_layers", arch.n_layers),
+                         ("num_attention_heads", arch.n_heads), ("head_dim", arch.hd),
+                         ("num_key_value_heads", arch.n_kv_heads),
+                         ("intermediate_size", arch.d_ff), ("vocab_size", arch.vocab),
+                         ("rms_norm_eps", arch.norm_eps), ("rope_theta", arch.rope_theta)):
+        if m[mine] != theirs:
+            raise ValueError(f"configuration file {mine}={m[mine]} but the "
+                             f"program runs {theirs}")
+
+
+# -- the plain reference ----------------------------------------------------
 
 
 def _mm(a, b, mode):

@@ -1,6 +1,6 @@
-"""Plain multinomial logistic regression: ``softmax(x W + b)``, mean cross
-entropy, float32 at ``Precision.HIGHEST`` (or every value rounded to the
-lower ``mode`` of ``llama.cast`` for the control)."""
+"""The multinomial logistic regression family: ``softmax(x W + b)``, mean
+cross entropy, float32 at ``Precision.HIGHEST`` (or every value rounded to
+the lower ``mode`` of ``precision.cast`` for the control)."""
 from __future__ import annotations
 
 import functools
@@ -8,9 +8,29 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .llama import HI, cast
+from .precision import HI, cast
 
-__all__ = ["loss", "grad"]
+__all__ = ["weights", "train_flops", "loss", "grad"]
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _weights(key, dim, n_classes):
+    return {"w": 0.01 * jax.random.normal(key, (dim, n_classes), jnp.float32),
+            "b": jnp.zeros((n_classes,), jnp.float32)}
+
+
+def weights(cfg: dict, key):
+    """W ~ N(0, 0.01^2), b = 0, float32."""
+    return _weights(key, cfg["dim"], cfg["n_classes"])
+
+
+def train_flops(m: dict, traffic: dict) -> float:
+    """Forward and backward of ``x W + b`` over one round's samples (cohort
+    x local steps x batch): 6 FLOPs per weight per row (the bias and the
+    softmax are not counted)."""
+    fed = traffic["federation"]
+    samples = fed["cohort"] * fed["local_steps"] * fed["batch_size"]
+    return 6.0 * samples * m["dim"] * m["n_classes"]
 
 
 def loss(p, x, y, mode="f32"):

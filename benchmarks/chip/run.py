@@ -4,11 +4,14 @@
     python3 benchmarks/chip/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name: the cell in ``BENCHMARK.json`` names its
-configuration (a file of sizes under ``configs/``) and its traffic
-(``traffic/<traffic>.json``, whose ``generator`` key names the module under
-``generators/`` that builds the system under test and drives its traffic); each per-layer
-metric is read by ``metrics/<metric>.py``.  Adding a cell, a configuration,
-a traffic mix or a metric adds files; this file holds no list of them.
+configuration (a file of sizes under ``configs/``, whose ``reference`` key
+names its family module, ``references/<family>.py``: the weights, the FLOP
+counts, the check of the program's sizes and the plain reference) and its
+traffic (``traffic/<traffic>.json``, whose ``generator`` key names the
+module under ``generators/`` that builds the system under test and drives
+its traffic); each per-layer metric is read by ``metrics/<metric>.py``.
+Adding a cell, a configuration, a model family, a traffic mix or a metric
+adds files; this file holds no list of them.
 
 A run: set-up (weights and traffic from ``--seed``, the compile cache, the
 first rounds or requests that the check compares), then ``--seconds`` of

@@ -147,8 +147,11 @@ def test_flop_counts_by_hand():
          "vocab_size": 32, "head_dim": 2, "num_attention_heads": 4,
          "num_key_value_heads": 2}
     per_layer = 8 * 8 + 2 * 8 * 4 + 8 * 8 + 3 * 8 * 16
-    assert counts.llama_matmul_params(m) == 2 * per_layer + 8 * 32
-    assert counts.llama_train_flops(m, 10, 5) == 10 * (
+    assert llama.matmul_params(m) == 2 * per_layer + 8 * 32
+    # a round of 10 tokens in sequences of 5
+    traffic = {"federation": {"cohort": 1, "local_steps": 1, "batch_size": 2},
+               "data": {"seq_len": 5}}
+    assert llama.train_flops(m, traffic) == 10 * (
         6 * (2 * per_layer + 256) + 3 * 4 * 2 * 4 * 2 * 5)
 
 
@@ -158,7 +161,7 @@ def test_flop_counts_by_hand():
 TINY = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
         "vocab_size": 256, "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
-        "torch_dtype": "float32", "model": "llama"}
+        "torch_dtype": "float32", "model": "llama", "reference": "references/llama.py"}
 
 
 def test_llama_reference_matches_the_program():
