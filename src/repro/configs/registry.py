@@ -28,6 +28,8 @@ ARCH_MODULES = {
     "llama3-405b": "repro.configs.llama3_405b",
     "arctic-480b": "repro.configs.arctic_480b",
     "llama-3.2-vision-11b": "repro.configs.llama3_2_vision_11b",
+    "granite-4.0-h-micro": "repro.configs.granite_4_0_h_micro",
+    "granite-4.0-h-micro-p1": "repro.configs.granite_4_0_h_micro_p1",
 }
 
 

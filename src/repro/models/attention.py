@@ -1,4 +1,4 @@
-"""Grouped-query attention with RoPE, soft-capping, sliding windows,
+"""Grouped-query attention with RoPE (or none), soft-capping, sliding windows,
 cross-attention, and cached decode — the attention substrate for every
 assigned architecture.
 
@@ -27,6 +27,8 @@ from repro.models.sharding import shard
 __all__ = [
     "init_attention",
     "attention",
+    "attn_scale",
+    "position_qk",
     "cross_attention",
     "init_kv_cache",
     "decode_attention",
@@ -66,11 +68,27 @@ def _project_qkv(params, cfg: ArchConfig, xq: jax.Array, xkv: jax.Array):
     return q, k, v
 
 
+def attn_scale(cfg: ArchConfig) -> float:
+    """The softmax scale on q.k: the configured one, else hd^-1/2."""
+    return cfg.attn_scale if cfg.attn_scale is not None else cfg.hd ** -0.5
+
+
+def position_qk(cfg: ArchConfig, q, k, positions):
+    """RoPE on q (B,S,KV,G,hd) and k (B,S,KV,hd) at ``positions`` (S,);
+    under ``position_embedding="nope"`` both pass unchanged."""
+    if cfg.position_embedding == "nope":
+        return q, k
+    cos, sin = rope_angles(positions, cfg.hd, cfg.rope_theta)  # (S, hd/2)
+    q = apply_rope(q, cos[None, :, None, None, :], sin[None, :, None, None, :])
+    k = apply_rope(k, cos[None, :, None, :], sin[None, :, None, :])
+    return q, k
+
+
 def _sdpa(cfg: ArchConfig, q, k, v, mask):
     """q (B,Sq,KV,G,hd); k,v (B,Skv,KV,hd); mask broadcastable (B,1,1,Sq,Skv)."""
     if cfg.attn_impl == "chunked" and k.shape[1] >= 512:
         return _sdpa_chunked(cfg, q, k, v, mask)
-    scale = cfg.hd ** -0.5
+    scale = attn_scale(cfg)
     logits = jnp.einsum(
         "bqkgh,bskh->bkgqs", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
@@ -93,7 +111,7 @@ def _sdpa_chunked(cfg: ArchConfig, q, k, v, mask, block: int = 512):
     while s_k % blk:
         blk //= 2
     n_blocks = s_k // blk
-    scale = hd ** -0.5
+    scale = attn_scale(cfg)
     qf = q.astype(jnp.float32) * scale
 
     def body(carry, i):
@@ -115,6 +133,10 @@ def _sdpa_chunked(cfg: ArchConfig, q, k, v, mask, block: int = 512):
         acc = acc * corr[..., None] + jnp.einsum("bkgqs,bskh->bkgqh", p, v_blk)
         return (acc, m_new, l_new), ()
 
+    # Backward recomputes each block's probabilities from the carry instead
+    # of saving them: the (Sq, Skv) matrix is not materialized in training
+    # either.
+    body = jax.checkpoint(body)
     acc0 = jnp.zeros((b, kv, g, s_q, hd), jnp.float32)
     m0 = jnp.full((b, kv, g, s_q), _NEG, jnp.float32)
     l0 = jnp.zeros((b, kv, g, s_q), jnp.float32)
@@ -147,10 +169,7 @@ def attention(
     """Full-sequence self-attention (train / prefill)."""
     b, s, _ = x.shape
     q, k, v = _project_qkv(params, cfg, x, x)
-    pos = jnp.arange(s)
-    cos, sin = rope_angles(pos, cfg.hd, cfg.rope_theta)
-    q = apply_rope(q, cos[None, :, None, None, :], sin[None, :, None, None, :])
-    k = apply_rope(k, cos[None, :, None, :], sin[None, :, None, :])
+    q, k = position_qk(cfg, q, k, jnp.arange(s))
     q = shard(q, "batch", "seq", "kv_heads", None, None)
     k = shard(k, "batch", "seq", "kv_heads", None)
     v = shard(v, "batch", "seq", "kv_heads", None)
@@ -201,9 +220,7 @@ def decode_attention(
     """
     b, one, _ = x.shape
     q, k_new, v_new = _project_qkv(params, cfg, x, x)
-    cos, sin = rope_angles(index[None], cfg.hd, cfg.rope_theta)  # (1, hd/2)
-    q = apply_rope(q, cos[None, :, None, None, :], sin[None, :, None, None, :])
-    k_new = apply_rope(k_new, cos[None, :, None, :], sin[None, :, None, :])
+    q, k_new = position_qk(cfg, q, k_new, index[None])
 
     k = jax.lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype), (0, index, 0, 0))
     v = jax.lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype), (0, index, 0, 0))
@@ -294,9 +311,7 @@ def paged_decode_attention(
     page_size = pool_k.shape[1]
 
     q, k_new, v_new = _project_qkv(params, cfg, x, x)
-    cos, sin = rope_angles(index[None], cfg.hd, cfg.rope_theta)  # (1, hd/2)
-    q = apply_rope(q, cos[None, :, None, None, :], sin[None, :, None, None, :])
-    k_new = apply_rope(k_new, cos[None, :, None, :], sin[None, :, None, :])
+    q, k_new = position_qk(cfg, q, k_new, index[None])
 
     # index is traced: page/slot stay inside the jitted program (no host sync,
     # no shape dependence on sequence length — the compile-once contract).

@@ -53,6 +53,7 @@ class ArchConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     conv_width: int = 4
+    conv_bias: bool = False  # mamba2: a bias on the depthwise causal conv
     shared_attn_every: int = 0  # zamba2: shared attention block cadence
 
     # encoder-decoder / multimodal
@@ -62,6 +63,13 @@ class ArchConfig:
     frontend_seq: int = 0  # number of frames / image patches
     frontend_dim: int = 0  # embedding dim delivered by the stubbed frontend
     scale_embed: bool = False  # gemma2: h *= sqrt(d_model)
+
+    # granite-4.0-h scalars; None leaves the op out of the program
+    position_embedding: str = "rope"  # "rope" | "nope" (no positional encoding)
+    attn_scale: float | None = None  # softmax scale on q.k (None: hd^-1/2)
+    embed_multiplier: float | None = None  # h = m * embed[tok]
+    residual_multiplier: float | None = None  # h += m * block(h)
+    logit_divisor: float | None = None  # logits = head(h) / m
 
     # numerics
     param_dtype: Any = jnp.bfloat16

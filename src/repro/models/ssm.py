@@ -20,7 +20,12 @@ import jax.numpy as jnp
 from repro.models.common import ArchConfig, rms_norm, uniform_init
 from repro.models.sharding import shard
 
+# The device scope around the scan (``repro.obs.MODEL_SCOPES``): the chunked
+# SSD of training and prefill, and the decode step's state update.
+SCAN_SCOPE = "ssm.scan"
+
 __all__ = [
+    "SCAN_SCOPE",
     "init_mamba2",
     "mamba2_block",
     "mamba2_decode_step",
@@ -53,7 +58,9 @@ def ssd_chunked(x, dt, a_log, b, c, d_skip, chunk: int = 128, return_state: bool
     # intra-chunk quadratic term: M[t,s] = exp(cum_t - cum_s) for s <= t
     seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q,Q,H)
     causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-    m = jnp.where(causal[None, None, :, :, None], jnp.exp(seg), 0.0)
+    # Mask before the exp: above the diagonal seg is positive and exp(seg)
+    # can overflow, and inf x 0 in backward would make the gradient NaN.
+    m = jnp.exp(jnp.where(causal[None, None, :, :, None], seg, -jnp.inf))
     cb = jnp.einsum("bcqn,bcsn->bcqs", cm, bm)  # (B,nc,Q,Q)
     y_intra = jnp.einsum("bcqs,bcqsh,bcshd->bcqhd", cb, m, xa)
 
@@ -98,6 +105,7 @@ def init_mamba2(cfg: ArchConfig, key: jax.Array) -> dict:
         # in_proj -> [z (d_in), x (d_in), B (n), C (n), dt (H)]
         "in_proj": uniform_init(ks[0], (d, 2 * d_in + 2 * n + n_heads), cfg.param_dtype),
         "conv_w": uniform_init(ks[1], (cfg.conv_width, conv_ch), cfg.param_dtype, scale=0.5),
+        **({"conv_b": jnp.zeros((conv_ch,), cfg.param_dtype)} if cfg.conv_bias else {}),
         "a_log": jnp.zeros((n_heads,), jnp.float32),
         "d_skip": jnp.ones((n_heads,), jnp.float32),
         "dt_bias": jnp.zeros((n_heads,), jnp.float32),
@@ -106,8 +114,9 @@ def init_mamba2(cfg: ArchConfig, key: jax.Array) -> dict:
     }
 
 
-def _causal_conv(x, w, state=None):
-    """Depthwise causal conv. x (B,S,C); w (W,C). state (B,W-1,C) for decode."""
+def _causal_conv(x, w, state=None, bias=None):
+    """Depthwise causal conv. x (B,S,C); w (W,C); bias (C,) or None.
+    state (B,W-1,C) for decode."""
     width = w.shape[0]
     if state is None:
         pad = jnp.zeros((x.shape[0], width - 1, x.shape[2]), x.dtype)
@@ -117,6 +126,8 @@ def _causal_conv(x, w, state=None):
     out = sum(
         xp[:, i : i + x.shape[1], :] * w[i][None, None, :] for i in range(width)
     )
+    if bias is not None:
+        out = out + bias[None, None, :]
     # keep the carried dtype stable across scan iterations (prefill replay)
     new_state = xp[:, -(width - 1) :, :]
     if state is not None:
@@ -140,7 +151,7 @@ def mamba2_block(
     bsz, s, d = x.shape
     proj = x @ params["in_proj"]
     z, xbc_raw, dt, d_in, n, n_heads = _split_proj(cfg, proj)
-    xbc, conv_tail = _causal_conv(xbc_raw, params["conv_w"])
+    xbc, conv_tail = _causal_conv(xbc_raw, params["conv_w"], bias=params.get("conv_b"))
     xbc = jax.nn.silu(xbc)
     xs = xbc[..., :d_in].reshape(bsz, s, n_heads, cfg.ssm_head_dim)
     b = xbc[..., d_in : d_in + n]
@@ -150,17 +161,23 @@ def mamba2_block(
     ch = min(chunk, s)
     while s % ch:
         ch //= 2
-    out = ssd_chunked(
-        xs, dt, params["a_log"], b, c, params["d_skip"], chunk=max(ch, 1),
-        return_state=return_state,
-    )
+    with jax.named_scope(SCAN_SCOPE):
+        out = ssd_chunked(
+            xs, dt, params["a_log"], b, c, params["d_skip"], chunk=max(ch, 1),
+            return_state=return_state,
+        )
     y, ssm_state = out if return_state else (out, None)
-    y = y.reshape(bsz, s, d_in)
-    y = rms_norm(y, params["norm_scale"], cfg.norm_eps) * jax.nn.silu(z)
-    y = y @ params["out_proj"]
+    y = _gated_norm(params, cfg, y.reshape(bsz, s, d_in), z) @ params["out_proj"]
     if return_state:
         return y, {"conv": conv_tail, "ssm": ssm_state}
     return y
+
+
+def _gated_norm(params: dict, cfg: ArchConfig, y, z):
+    """Mamba2's RMSNormGated: the gate first, then the norm over all d_in
+    channels (one group), in float32: ``rms_norm(y * silu(z))``."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return rms_norm(g, params["norm_scale"], cfg.norm_eps).astype(y.dtype)
 
 
 def init_mamba2_state(cfg: ArchConfig, batch: int, dtype=jnp.float32) -> dict:
@@ -179,19 +196,20 @@ def mamba2_decode_step(params: dict, cfg: ArchConfig, x: jax.Array, state: dict)
     bsz = x.shape[0]
     proj = x @ params["in_proj"]
     z, xbc, dt, d_in, n, n_heads = _split_proj(cfg, proj)
-    xbc, conv_state = _causal_conv(xbc, params["conv_w"], state["conv"])
+    xbc, conv_state = _causal_conv(xbc, params["conv_w"], state["conv"], params.get("conv_b"))
     xbc = jax.nn.silu(xbc)
     xs = xbc[..., :d_in].reshape(bsz, n_heads, cfg.ssm_head_dim)
     b = xbc[:, 0, d_in : d_in + n]  # (B,N)
     c = xbc[:, 0, d_in + n :]
-    dtf = jax.nn.softplus((dt[:, 0] + params["dt_bias"][None]).astype(jnp.float32))  # (B,H)
-    af = -jnp.exp(params["a_log"].astype(jnp.float32))
-    decay = jnp.exp(dtf * af[None])  # (B,H)
-    h = state["ssm"] * decay[..., None, None] + jnp.einsum(
-        "bhd,bn,bh->bhdn", xs.astype(jnp.float32), b.astype(jnp.float32), dtf
-    )
-    y = jnp.einsum("bhdn,bn->bhd", h, c.astype(jnp.float32))
-    y = y + params["d_skip"][None, :, None] * xs.astype(jnp.float32)
+    with jax.named_scope(SCAN_SCOPE):
+        dtf = jax.nn.softplus((dt[:, 0] + params["dt_bias"][None]).astype(jnp.float32))  # (B,H)
+        af = -jnp.exp(params["a_log"].astype(jnp.float32))
+        decay = jnp.exp(dtf * af[None])  # (B,H)
+        h = state["ssm"] * decay[..., None, None] + jnp.einsum(
+            "bhd,bn,bh->bhdn", xs.astype(jnp.float32), b.astype(jnp.float32), dtf
+        )
+        y = jnp.einsum("bhdn,bn->bhd", h, c.astype(jnp.float32))
+        y = y + params["d_skip"][None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(bsz, 1, d_in).astype(x.dtype)
-    y = rms_norm(y, params["norm_scale"], cfg.norm_eps) * jax.nn.silu(z)
+    y = _gated_norm(params, cfg, y, z)
     return y @ params["out_proj"], {"conv": conv_state, "ssm": h}

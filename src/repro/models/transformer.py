@@ -31,7 +31,7 @@ from repro.models.attention import (
     decode_attention,
     init_attention,
 )
-from repro.models.common import ArchConfig, apply_rope, rms_norm, rope_angles, softcap, uniform_init
+from repro.models.common import ArchConfig, rms_norm, softcap, uniform_init
 from repro.models.mlp import init_mlp, mlp
 from repro.models.sharding import shard
 
@@ -48,6 +48,7 @@ __all__ = [
 MOE_AUX_COEF = 0.01
 
 ATTN_KINDS = {"attn", "attn_local", "moe", "shared_attn", "dec"}
+RECURRENT_KINDS = ("mamba2", "mamba2_mlp", "mlstm", "slstm")  # O(1) decode state
 
 
 # ---------------------------------------------------------------------------
@@ -75,6 +76,13 @@ def _init_block(kind: str, cfg: ArchConfig, key: jax.Array) -> dict:
         }
     if kind == "mamba2":
         return {"ln1": jnp.zeros((d,), dt), "ssm": ssm_mod.init_mamba2(cfg, ks[0])}
+    if kind == "mamba2_mlp":  # granite-4.0-h: a Mamba2 mixer, then an MLP
+        return {
+            "ln1": jnp.zeros((d,), dt),
+            "ssm": ssm_mod.init_mamba2(cfg, ks[0]),
+            "ln2": jnp.zeros((d,), dt),
+            "mlp": init_mlp(cfg, ks[1]),
+        }
     if kind == "mlstm":
         return {"ln1": jnp.zeros((d,), dt), "cell": xlstm_mod.init_mlstm(cfg, ks[0])}
     if kind == "slstm":
@@ -152,6 +160,13 @@ def param_count(params) -> int:
 # ---------------------------------------------------------------------------
 
 
+def _residual(cfg: ArchConfig, h, y):
+    """h + y, or h + m * y under ``residual_multiplier`` m."""
+    if cfg.residual_multiplier is not None:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return h + y
+
+
 def _apply_block(
     kind: str,
     p: dict,
@@ -185,15 +200,15 @@ def _apply_block(
                 want_cache=(mode == "prefill"), max_seq=max_seq,
             )
             cache = kv
-        h = h + y
+        h = _residual(cfg, h, y)
         x = rms_norm(h, p["ln2"], cfg.norm_eps)
         if kind == "moe":
             y, aux = moe_mod.moe_ffn(p["moe"], cfg, x)
         else:
             y = mlp(p["mlp"], cfg, x)
-        return h + y, cache, aux
+        return _residual(cfg, h, y), cache, aux
 
-    if kind == "mamba2":
+    if kind in ("mamba2", "mamba2_mlp"):
         x = rms_norm(h, p["ln1"], cfg.norm_eps)
         if mode == "decode":
             y, cache = ssm_mod.mamba2_decode_step(p["ssm"], cfg, x, cache)
@@ -203,7 +218,11 @@ def _apply_block(
             y, cache = ssm_mod.mamba2_block(p["ssm"], cfg, x, return_state=True)
         else:
             y = ssm_mod.mamba2_block(p["ssm"], cfg, x)
-        return h + y, cache, aux
+        h = _residual(cfg, h, y)
+        if kind == "mamba2_mlp":
+            y = mlp(p["mlp"], cfg, rms_norm(h, p["ln2"], cfg.norm_eps))
+            h = _residual(cfg, h, y)
+        return h, cache, aux
 
     if kind in ("mlstm", "slstm"):
         mod_step = (
@@ -285,10 +304,7 @@ def _full_attention(p, cfg, x, *, causal=True, window=None, want_cache=False, ma
     b, s, _ = x.shape
     q, k, v = _project_qkv(p, cfg, x, x)
     if causal:  # RoPE only on causal (decoder) attention; whisper enc uses abs pos
-        pos = jnp.arange(s)
-        cos, sin = rope_angles(pos, cfg.hd, cfg.rope_theta)
-        q = apply_rope(q, cos[None, :, None, None, :], sin[None, :, None, None, :])
-        k = apply_rope(k, cos[None, :, None, :], sin[None, :, None, :])
+        q, k = attn_mod.position_qk(cfg, q, k, jnp.arange(s))
     q = shard(q, "batch", "seq", "kv_heads", None, None)
     k = shard(k, "batch", "seq", "kv_heads", None)
     v = shard(v, "batch", "seq", "kv_heads", None)
@@ -374,32 +390,29 @@ def _run_stack(
     # scan wants a single pytree of xs with uniform leading dim
     reps = cfg.pattern_repeats()
 
+    def block(kind, p, h, cache):
+        return _apply_block(
+            kind, p, cfg, h, mode=mode, cache=cache, index=index,
+            cross_src=cross_src, shared=shared, max_seq=max_seq,
+        )
+
+    if mode == "train" and cfg.remat == "full":
+        # Gradient checkpointing per block: backward recomputes one block's
+        # forward at a time instead of saving O(S^2) attention (or SSD)
+        # intermediates — mandatory at production sequence lengths.  Each
+        # block of a many-slot group is its own checkpoint, so backward holds
+        # one block's intermediates, not the whole group's.
+        block = jax.checkpoint(block, static_argnums=(0,))
+
     def body(h, slot_inputs):
         blocks, slot_caches = slot_inputs
         aux_sum = jnp.zeros((), jnp.float32)
         new_caches = []
         for j, kind in enumerate(cfg.block_pattern):
-            h, nc, aux = _apply_block(
-                kind,
-                blocks[j],
-                cfg,
-                h,
-                mode=mode,
-                cache=None if slot_caches[j] is None else slot_caches[j],
-                index=index,
-                cross_src=cross_src,
-                shared=shared,
-                max_seq=max_seq,
-            )
+            h, nc, aux = block(kind, blocks[j], h, slot_caches[j])
             aux_sum = aux_sum + aux
             new_caches.append(nc)
         return h, (aux_sum, new_caches)
-
-    if mode == "train" and cfg.remat == "full":
-        # Gradient checkpointing on the layer-group body: backward recomputes
-        # the group forward instead of saving O(S^2) attention intermediates
-        # per layer — mandatory at production sequence lengths.
-        body = jax.checkpoint(body)
 
     h, (aux_per_group, out_caches) = jax.lax.scan(body, h, xs)
     aux = jnp.sum(aux_per_group)
@@ -408,19 +421,32 @@ def _run_stack(
     return h, aux, out_caches
 
 
-def forward(params, cfg: ArchConfig, tokens: jax.Array, aux_embeds=None):
-    """Training forward: tokens (B, S) -> (logits (B,S,V), aux_loss)."""
+def _embed(params, cfg: ArchConfig, tokens):
+    """Token embeddings, scaled as the configuration says."""
     h = params["embed"][tokens]
     if cfg.scale_embed:
         h = h * jnp.asarray(cfg.d_model**0.5, h.dtype)
-    h = shard(h, "batch", "seq", None)
+    if cfg.embed_multiplier is not None:
+        h = h * jnp.asarray(cfg.embed_multiplier, h.dtype)
+    return h
+
+
+def _head(params, cfg: ArchConfig, h):
+    """Logits of hidden states ``h``: final norm, LM head, logit divisor and
+    soft cap."""
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    logits = h @ params.get("lm_head", params["embed"].T)
+    if cfg.logit_divisor is not None:
+        logits = logits / jnp.asarray(cfg.logit_divisor, logits.dtype)
+    return softcap(logits, cfg.final_softcap)
+
+
+def forward(params, cfg: ArchConfig, tokens: jax.Array, aux_embeds=None):
+    """Training forward: tokens (B, S) -> (logits (B,S,V), aux_loss)."""
+    h = shard(_embed(params, cfg, tokens), "batch", "seq", None)
     cross_src = _cross_source(params, cfg, aux_embeds)
     h, aux, _ = _run_stack(params, cfg, h, mode="train", cross_src=cross_src)
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    head = params.get("lm_head", params["embed"].T)
-    logits = h @ head
-    logits = softcap(logits, cfg.final_softcap)
-    logits = shard(logits, "batch", "seq", "vocab")
+    logits = shard(_head(params, cfg, h), "batch", "seq", "vocab")
     return logits, aux
 
 
@@ -458,7 +484,7 @@ def init_caches(cfg: ArchConfig, batch: int, max_seq: int, page_size: int | None
     def one(kind):
         if kind in ("attn", "attn_local", "moe", "shared_attn"):
             return kv_cache()
-        if kind == "mamba2":
+        if kind in ("mamba2", "mamba2_mlp"):
             return ssm_mod.init_mamba2_state(cfg, batch)
         if kind == "mlstm":
             return xlstm_mod.init_mlstm_state(cfg, batch)
@@ -505,17 +531,12 @@ def prefill(
     (``attention.pack_kv_to_pages``) before returning: prefill computes in
     the cheap contiguous layout, decode indexes through the page table — the
     prefill->decode hand-off of the serving engine."""
-    h = params["embed"][tokens]
-    if cfg.scale_embed:
-        h = h * jnp.asarray(cfg.d_model**0.5, h.dtype)
-    h = shard(h, "batch", "seq", None)
+    h = shard(_embed(params, cfg, tokens), "batch", "seq", None)
     cross_src = _cross_source(params, cfg, aux_embeds)
     h, _, caches = _run_stack(
         params, cfg, h, mode="prefill", cross_src=cross_src, max_seq=max_seq
     )
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    head = params.get("lm_head", params["embed"].T)
-    logits = softcap(h[:, -1:] @ head, cfg.final_softcap)
+    logits = _head(params, cfg, h[:, -1:])
     if page_size is not None:
         caches = _caches_to_pages(cfg, caches, page_size)
     return logits, caches
@@ -541,11 +562,6 @@ def _caches_to_pages(cfg: ArchConfig, caches, page_size: int):
 
 def decode_step(params, cfg: ArchConfig, token: jax.Array, caches, index: jax.Array):
     """token (B, 1) int32; index = number of tokens already in cache."""
-    h = params["embed"][token]
-    if cfg.scale_embed:
-        h = h * jnp.asarray(cfg.d_model**0.5, h.dtype)
+    h = _embed(params, cfg, token)
     h, _, caches = _run_stack(params, cfg, h, mode="decode", caches=caches, index=index)
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    head = params.get("lm_head", params["embed"].T)
-    logits = softcap(h @ head, cfg.final_softcap)
-    return logits, caches
+    return _head(params, cfg, h), caches

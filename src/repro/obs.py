@@ -9,6 +9,10 @@ Both land on the profiler's one clock, beside the device operations:
   code; a fusion takes its root instruction's ``op_name``.  Scopes are one
   level deep: no ``round.*`` scope opens inside another, so each device op
   belongs to at most one layer, and an op in none belongs to the segment loop.
+* ``MODEL_SCOPES`` name a model layer inside a round scope
+  (``.../round.local_train/.../ssm.scan/...``); the round's scope stays the
+  first ``round.*`` component, so a model scope splits a layer's time and
+  moves none between layers.
 * ``HOST_SPANS`` are host phases, each written as a
   ``jax.profiler.TraceAnnotation`` named ``"repro." + name`` by ``span``:
   the serve engine's prefill, decode step and swap, the segment call, and the
@@ -22,7 +26,7 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["DEVICE_SCOPES", "HOST_SPANS", "span"]
+__all__ = ["DEVICE_SCOPES", "MODEL_SCOPES", "HOST_SPANS", "span"]
 
 DEVICE_SCOPES = (
     "round.solve",  # sampler.probabilities, the water-filling kernel included
@@ -34,6 +38,13 @@ DEVICE_SCOPES = (
     "round.sampler_update",  # the feedback scatter and sampler.update
     "round.faults",  # availability, deadline and buffered-async steps
     "round.eval",  # the periodic accuracy cond
+)
+
+# Model-level device scopes: opened inside a layer of the round (the scan of
+# a Mamba2 mixer runs inside ``round.local_train``), so they never take the
+# place of the round's own scope in an op_name.
+MODEL_SCOPES = (
+    "ssm.scan",  # models/ssm.py: the chunked SSD and the decode-step state update
 )
 
 HOST_SPANS = (

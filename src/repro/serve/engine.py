@@ -49,6 +49,19 @@ def _leaf_avals(tree) -> list:
     ]
 
 
+def _held_bytes(cfg, caches) -> tuple[int, int]:
+    """(bytes in KV pages, bytes in recurrent state) of a paged cache list,
+    one entry per pattern slot (``transformer.init_caches``' layout)."""
+    kv = state = 0
+    for kind, cache in zip(cfg.block_pattern, caches):
+        for path, x in jax.tree_util.tree_leaves_with_path(cache):
+            if kind in transformer.RECURRENT_KINDS:
+                state += x.nbytes
+            elif any(getattr(k, "key", None) in ("pool_k", "pool_v") for k in path):
+                kv += x.nbytes
+    return int(kv), int(state)
+
+
 def _sample_token(logits: jax.Array, key: jax.Array, temperature: jax.Array):
     """(B, 1, V) logits -> (B, 1) int32 next tokens.
 
@@ -130,6 +143,11 @@ class ServeEngine:
         self.swaps_rejected = 0
         self.decode_tokens = 0
         self.decode_seconds = 0.0
+        # Bytes the in-flight batch holds on the device: KV pages of the
+        # attention slots, and the O(1) recurrent state of the Mamba2 / xLSTM
+        # slots (set by start(); the shapes are fixed for the engine's life).
+        self.kv_page_bytes = 0
+        self.recurrent_state_bytes = 0
 
         def _prefill(p, prompts, key, temperature):
             logits, caches = transformer.prefill(
@@ -187,6 +205,7 @@ class ServeEngine:
                 self._params, prompts, self._next_key(), self._temp()
             )
         self._tok, self._caches = tok, caches
+        self.kv_page_bytes, self.recurrent_state_bytes = _held_bytes(self.cfg, caches)
         self._index = int(prompts.shape[1])
         self._out = [tok]
         return tok
@@ -241,6 +260,8 @@ class ServeEngine:
             "swaps_rejected": self.swaps_rejected,
             "decode_tokens": self.decode_tokens,
             "decode_seconds": self.decode_seconds,
+            "kv_page_bytes": self.kv_page_bytes,
+            "recurrent_state_bytes": self.recurrent_state_bytes,
         }
 
     # -- the hot swap --------------------------------------------------------

@@ -120,6 +120,7 @@ def test_param_counts_full_configs():
         "zamba2-1.2b": 1.2e9,
         "llama-3.2-vision-11b": 11e9,
         "whisper-small": 240e6,
+        "granite-4.0-h-micro": 3.19e9,
     }
     for name, want in expectations.items():
         cfg = get_config(name)

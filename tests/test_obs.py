@@ -160,9 +160,13 @@ def test_serve_spans_nest_and_counters_count(tmp_path):
     for a, b in zip(by["repro.serve.step.prep"], by["repro.serve.step.dispatch"]):
         assert a.end <= b.start
 
+    # Two layers' K and V pools of (batch 2 x 4 pages, page 8, 2 KV heads,
+    # head size 16) float32; a dense model holds no recurrent state.
     assert eng.counters() == {"prefills": 2, "host_syncs": 3, "swaps": 1,
                               "swaps_rejected": 0, "decode_tokens": 6,
-                              "decode_seconds": eng.decode_seconds}
+                              "decode_seconds": eng.decode_seconds,
+                              "kv_page_bytes": 2 * 2 * (8 * 8 * 2 * 16 * 4),
+                              "recurrent_state_bytes": 0}
     rogue = dict(params, rogue=jnp.zeros((3,)))
     with pytest.raises(ValueError, match="treedef"):
         eng.swap_params(rogue)

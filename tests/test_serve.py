@@ -27,7 +27,7 @@ from repro.serve import (
 
 
 # One reduced config per architecture family (the fed_lm zoo set): dense,
-# top-k MoE, mamba2 hybrid, and xLSTM all flow through the same paged path.
+# top-k MoE, mamba2 hybrids, and xLSTM all flow through the same paged path.
 SERVE_ARCHS = {
     "dense": ("smollm-360m", dict(n_layers=2, d_model=64, d_ff=128)),
     "moe": ("qwen3-moe-235b-a22b", {}),
@@ -36,6 +36,12 @@ SERVE_ARCHS = {
         dict(n_layers=4, block_pattern=("mamba2", "mamba2", "mamba2", "shared_attn")),
     ),
     "xlstm": ("xlstm-125m", {}),
+    # Mamba-2 layers with MLPs beside a NoPE attention layer, with granite's
+    # embedding, residual, attention and logit scalars.
+    "granite": (
+        "granite-4.0-h-micro-p1",
+        dict(n_layers=3, block_pattern=("mamba2_mlp", "attn", "mamba2_mlp")),
+    ),
 }
 
 
